@@ -10,8 +10,8 @@ normalizes each into a :class:`PallasInfo` the checks can interrogate:
 
   * block geometry per operand/output (shape, backing array, index_map as a
     callable evaluated through ``jaxpr_as_fun`` — still device-free);
-  * grid + per-dim semantics (``mosaic.dimension_semantics``; absent means
-    every dim is sequential/"arbitrary");
+  * grid + per-dim semantics (``pltpu.CompilerParams.dimension_semantics``;
+    absent means every dim is sequential/"arbitrary");
   * the kernel body jaxpr, with ref reads (``get``) and writes (``swap``)
     collected per *root* ref through nested sub-jaxprs (``pl.when`` lowers
     to ``cond``), so RMW and compute-dtype contracts see conditional
@@ -28,10 +28,10 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax.core is the public home in 0.4.x; _src is the fallback spelling
-    from jax.core import ClosedJaxpr, Jaxpr, Literal, Var, jaxpr_as_fun
-except ImportError:  # pragma: no cover
-    from jax._src.core import ClosedJaxpr, Jaxpr, Literal, Var, jaxpr_as_fun
+from jax._src.pallas.core import Squeezed
+from jax._src.state.types import AbstractRef
+from jax.experimental.pallas import tpu as pltpu
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var, jaxpr_as_fun
 
 
 def trace_entry(fn: Callable, *args, **kwargs) -> ClosedJaxpr:
@@ -140,7 +140,10 @@ class PallasInfo:
 
 
 def _norm_block_shape(shape) -> Tuple[int, ...]:
-    return tuple(1 if d is None else int(d) for d in tuple(shape))
+    """Block dims as ints: a squeezed dim (``None`` in the BlockSpec) is 1,
+    every other dim (``pl.Blocked`` and kin) its ``block_size``."""
+    return tuple(1 if isinstance(d, Squeezed) else int(d.block_size)
+                 for d in shape)
 
 
 def _index_map_fn(index_map_jaxpr: ClosedJaxpr,
@@ -156,11 +159,7 @@ def _index_map_fn(index_map_jaxpr: ClosedJaxpr,
     representative table contents."""
     invars = index_map_jaxpr.jaxpr.invars
     n_out = len(index_map_jaxpr.jaxpr.outvars)
-    try:
-        from jax._src.state.types import AbstractRef as _AbstractRef
-    except ImportError:  # pragma: no cover
-        _AbstractRef = ()
-    if any(isinstance(v.aval, _AbstractRef) for v in invars):
+    if any(isinstance(v.aval, AbstractRef) for v in invars):
         # Scalar-prefetch operands arrive as (S)MEM refs whose reads are
         # stateful `get`s; discharge turns them into plain array inputs
         # (appending the final ref values to the outputs, truncated below).
@@ -191,11 +190,10 @@ def pallas_info(eqn, scalar_samples: Optional[Sequence[Any]] = None) -> PallasIn
     n_out = len(eqn.outvars)
     n_in = len(eqn.invars) - n_idx
 
-    cp = eqn.params.get("compiler_params") or {}
     sem = None
-    if isinstance(cp, dict):
-        mosaic = cp.get("mosaic") or {}
-        sem = mosaic.get("dimension_semantics") if isinstance(mosaic, dict) else None
+    for cp in (eqn.params.get("compiler_params") or {}).values():
+        if isinstance(cp, pltpu.CompilerParams):
+            sem = cp.dimension_semantics
     if sem is None:
         sem = ("arbitrary",) * len(grid)
     sem = tuple(str(s) for s in sem)
@@ -205,7 +203,7 @@ def pallas_info(eqn, scalar_samples: Optional[Sequence[Any]] = None) -> PallasIn
         f"block_mappings ({len(mappings)}) != inputs ({n_in}) + outputs ({n_out})")
 
     def mk(role: str, slot: int, bm) -> BlockInfo:
-        sds = bm.array_shape_dtype
+        sds = bm.array_aval
         return BlockInfo(
             role=role, slot=slot,
             block_shape=_norm_block_shape(bm.block_shape),

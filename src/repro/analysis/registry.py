@@ -305,7 +305,8 @@ def case_args(entry: KernelEntry, case: Case) -> Tuple[jax.ShapeDtypeStruct, ...
             shape = (case.shape[0], 1)
         elif role == "pool":
             kw = case.kwargs
-            shape = (kw["pages"], kw["page"], 2 * kw["kv"], case.shape[3])
+            shape = _pa.pool_shape(kw["pages"], kw["page"], kw["kv"],
+                                   case.shape[3])
         elif role == "table":
             shape = (case.shape[0], case.kwargs["max_pages"])
         elif role == "lengths":

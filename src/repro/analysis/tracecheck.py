@@ -28,17 +28,13 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Var
 
 from repro.configs import get_reduced
 from repro.core import rules_as_tree, table3_rules
 from repro.core.slim_adam import slim_adam
 from repro.train.guard import Guard, GuardConfig
 from repro.train.step import make_train_step
-
-try:
-    from jax.core import Var, get_aval
-except ImportError:  # pragma: no cover
-    from jax._src.core import Var, get_aval
 
 from .report import PassResult
 
@@ -131,8 +127,8 @@ def check_guard_aval_stability(result: PassResult,
                    "guard did not react to a 50x loss spike — the transition "
                    "this check exercises no longer exists; update tracecheck")
         return
-    avals_before = [str(get_aval(x)) for x in jax.tree_util.tree_leaves(before)]
-    avals_after = [str(get_aval(x)) for x in jax.tree_util.tree_leaves(after)]
+    avals_before = [str(jax.typeof(x)) for x in jax.tree_util.tree_leaves(before)]
+    avals_after = [str(jax.typeof(x)) for x in jax.tree_util.tree_leaves(after)]
     if avals_before != avals_after:
         result.add("aval-stable", where,
                    f"controls avals changed across a guard backoff "

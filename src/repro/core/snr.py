@@ -139,7 +139,6 @@ def _sharded_snr_exec(shape: Tuple[int, ...], dtype, dims: Tuple[int, ...], spec
     ``snr_op``)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..sharding.logical import shard_map
     from ..sharding.shardspec import even_spec, owning_axes
 
     ndim = len(shape)
@@ -163,8 +162,8 @@ def _sharded_snr_exec(shape: Tuple[int, ...], dtype, dims: Tuple[int, ...], spec
             s = jax.lax.pmean(s, kept_axes)
         return s
 
-    return jax.jit(shard_map(local_fn, mesh=mesh, in_specs=(spec_e,),
-                             out_specs=P(), check_rep=False))
+    return jax.jit(jax.shard_map(local_fn, mesh=mesh, in_specs=(spec_e,),
+                                 out_specs=P(), check_vma=False))
 
 
 def _sharded_snr(v: jnp.ndarray, dims: Tuple[int, ...], spec, mesh, backend: str) -> jnp.ndarray:

@@ -13,10 +13,13 @@ comfortably inside the ~16 MiB VMEM with double buffering.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .tiling import default_interpret
 
 # Default (sublane, lane) tile for the dense kernels. The lane width is the
 # single source of truth for any layout folded to match these tiles
@@ -57,7 +60,7 @@ def _adam_kernel(p_ref, g_ref, m_ref, v_ref, scal_ref,
 
 def fused_adam(p, g, m, v, *, lr: float, b1: float = 0.9, b2: float = 0.95,
                eps: float = 1e-8, wd: float = 0.0, count: int = 1,
-               block: tuple = BLOCK, interpret: bool = True):
+               block: tuple = BLOCK, interpret: Optional[bool] = None):
     """p, g: (R, C) any float dtype; m, v: (R, C) fp32. Returns (p', m', v')."""
     r, c = p.shape
     tr = min(block[0], r)
@@ -87,7 +90,7 @@ def fused_adam(p, g, m, v, *, lr: float, b1: float = 0.9, b2: float = 0.95,
             jax.ShapeDtypeStruct((r, c), jnp.float32),
             jax.ShapeDtypeStruct((r, c), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(p, g, m, v, scal)
 
 
@@ -127,7 +130,7 @@ def _adam_precond_kernel(g_ref, m_ref, v_ref, scal_ref, u_out, m_out, v_out,
 
 
 def adam_precond(g, m, v, *, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
-                 count=1, block: tuple = BLOCK, interpret: bool = True,
+                 count=1, block: tuple = BLOCK, interpret: Optional[bool] = None,
                  with_health: bool = False):
     """Preconditioned Adam update only: (g, m, v) -> (u, m', v'), all fp32.
 
@@ -169,5 +172,5 @@ def adam_precond(g, m, v, *, b1: float = 0.9, b2: float = 0.999, eps: float = 1e
         in_specs=[spec, spec, spec, pl.BlockSpec((2,), lambda i, j: (0,))],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(g, m, v, scal)

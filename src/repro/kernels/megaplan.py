@@ -76,7 +76,7 @@ from .ops import CanonND, canon_apply, canon_restore, leaf_plan
 from .slim_update import (FINALIZE_BUFS, PARTIAL_BUFS, PRECOND_BUFS,
                           PRECOND_SNR_BUFS)
 from .snr_stats import centered_line_stats
-from .tiling import pad_kept, strip_grid, trim_kept
+from .tiling import default_interpret, pad_kept, strip_grid, trim_kept
 
 # Live full-size fp32 buffers per instance (the n_bufs VMEM-fitting
 # argument / the kernelcheck BUFS bracket). The slim mega kernels hold the
@@ -350,7 +350,7 @@ def _mega_adam_kernel(g_ref, m_ref, v_ref, bc1_ref, bc2_ref, u_out, m_out,
 
 def mega_adam_update(g, m, v, bc1, bc2, *, b1=0.9, b2=0.999, eps=1e-8,
                      with_health: bool = False, block: int = _ADAM_BLOCK,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """Dense Adam over a lane-folded (rows, LANES) super-tensor with per-row
     bias-correction lines ``bc1`` / ``bc2`` (rows, 1). Returns
     ``(u, m', v')`` (+ per-row ``(nf, ss)`` health lines with
@@ -379,7 +379,7 @@ def mega_adam_update(g, m, v, bc1, bc2, *, b1=0.9, b2=0.999, eps=1e-8,
         out_specs=[full] * 3 + [line] * n_h,
         out_shape=([jax.ShapeDtypeStruct((r, c), jnp.float32)] * 3
                    + [jax.ShapeDtypeStruct((r, 1), jnp.float32)] * n_h),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(g, m, v, bc1, bc2)
 
 
@@ -418,7 +418,7 @@ def mega_slim_update_batched(g, m, v_line, bc1, bc2, *, axis: int, b1=0.9,
                              b2=0.95, eps=1e-8, with_snr: bool = False,
                              with_health: bool = False,
                              block: Optional[int] = None,
-                             interpret: bool = True):
+                             interpret: Optional[bool] = None):
     """Fused SlimAdam precondition over a (B, R, C) super-tensor whose kept
     axis concatenates same-line-geometry leaves; ``bc1`` / ``bc2`` are
     per-line bias-correction operands (:func:`segment_lines`). Per line the
@@ -453,7 +453,7 @@ def mega_slim_update_batched(g, m, v_line, bc1, bc2, *, axis: int, b1=0.9,
         out_shape=([jax.ShapeDtypeStruct((b, r, c), jnp.float32)] * 2
                    + [jax.ShapeDtypeStruct(line_shape, jnp.float32)]
                    * (1 + n_extra)),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(g, m, v_line, bc1, bc2)
 
 
@@ -487,7 +487,7 @@ def mega_slim_partial_stats_batched(g, m, *, axis: int, b1=0.9,
                                     with_snr: bool = False,
                                     with_health: bool = False,
                                     block: Optional[int] = None,
-                                    interpret: bool = True):
+                                    interpret: Optional[bool] = None):
     """Pass 1 of the grouped psum pair: m' plus per-line partial g^2 sums
     (un-normalized — the caller's cross-shard ``lax.psum`` completes them
     per leaf). ``with_snr`` adds the 3 centered partial-stat lines,
@@ -515,7 +515,7 @@ def mega_slim_partial_stats_batched(g, m, *, axis: int, b1=0.9,
         out_shape=([jax.ShapeDtypeStruct((b, r, c), jnp.float32)]
                    + [jax.ShapeDtypeStruct(line_shape, jnp.float32)]
                    * (1 + n_extra)),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(g, m)
 
 
@@ -535,7 +535,7 @@ def _mega_finalize_owner_kernel(m_ref, v_ref, bc1_ref, bc2_ref, u_out, *, eps):
 
 def mega_slim_finalize_batched(m_new, v_line, bc1, bc2, *, axis: int, ek=None,
                                b2=0.95, eps=1e-8, block: Optional[int] = None,
-                               interpret: bool = True):
+                               interpret: Optional[bool] = None):
     """Pass 2 of the grouped psum pair. With completed per-leaf mean lines
     ``ek`` returns ``(u, v_line')``; with ``ek=None`` (owner-write form,
     ``v_line`` already the psum-completed moment) returns ``u`` alone."""
@@ -562,7 +562,7 @@ def mega_slim_finalize_batched(m_new, v_line, bc1, bc2, *, axis: int, ek=None,
             in_specs=[sg.full, sg.line, sg.line, sg.line],
             out_specs=[sg.full],
             out_shape=[jax.ShapeDtypeStruct((b, r, c), jnp.float32)],
-            interpret=interpret,
+            interpret=default_interpret(interpret),
         )(m_new, v_line, bc1, bc2)[0]
     kernel = functools.partial(_mega_finalize_ek_kernel, b2=b2, eps=eps)
     return pl.pallas_call(
@@ -572,5 +572,5 @@ def mega_slim_finalize_batched(m_new, v_line, bc1, bc2, *, axis: int, ek=None,
         out_specs=[sg.full, sg.line],
         out_shape=[jax.ShapeDtypeStruct((b, r, c), jnp.float32),
                    jax.ShapeDtypeStruct(line_shape, jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(m_new, v_line, bc1, bc2, ek)

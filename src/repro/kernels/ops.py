@@ -63,7 +63,7 @@ from .snr_stats import (
     snr_stats_centered_partial,
     snr_stats_centered_partial_batched,
 )
-from .tiling import strip_fits
+from .tiling import default_interpret, strip_fits
 
 __all__ = ["fused_adam_op", "slim_update_op", "slim_update_nd", "snr_op",
            "snr_partial_op", "fused_adam", "slim_update", "slim_update_major",
@@ -76,13 +76,6 @@ __all__ = ["fused_adam_op", "slim_update_op", "slim_update_nd", "snr_op",
            "snr_stats_centered_partial_batched", "CanonND", "Canon2D",
            "canon_nd", "canon2d", "canon_apply", "canon_restore", "LeafPlan",
            "leaf_plan", "default_interpret"]
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode everywhere except a real TPU backend (where the
-    compiled kernel is the point; elsewhere the interpreter is the
-    correctness harness)."""
-    return jax.default_backend() != "tpu"
 
 
 class CanonND(NamedTuple):
@@ -270,7 +263,7 @@ def leaf_plan(shape: Tuple[int, ...], dtype, dims: Tuple[int, ...], *,
 
 @functools.partial(jax.jit, static_argnames=("lr", "b1", "b2", "eps", "wd", "count", "interpret"))
 def fused_adam_op(p, g, m, v, *, lr, b1=0.9, b2=0.95, eps=1e-8, wd=0.0, count=1,
-                  interpret=True):
+                  interpret=None):
     shape = p.shape
     p2 = p.reshape(-1, shape[-1]) if p.ndim != 2 else p
     g2 = g.reshape(p2.shape)
@@ -283,7 +276,7 @@ def fused_adam_op(p, g, m, v, *, lr, b1=0.9, b2=0.95, eps=1e-8, wd=0.0, count=1,
 
 @functools.partial(jax.jit, static_argnames=("axis", "lr", "b1", "b2", "eps", "wd", "count", "interpret"))
 def slim_update_op(p, g, m, v_red, *, axis: int, lr, b1=0.9, b2=0.95, eps=1e-8,
-                   wd=0.0, count=1, interpret=True):
+                   wd=0.0, count=1, interpret=None):
     """2-D params; ``axis`` is the compressed (reduced) dim. v_red keeps the
     reduced dim as size 1 (matching repro.core.slim_adam state layout).
     axis=0 runs the major-axis (sublane-reduction) kernel — no transpose."""
@@ -297,7 +290,7 @@ def slim_update_op(p, g, m, v_red, *, axis: int, lr, b1=0.9, b2=0.95, eps=1e-8,
 
 @functools.partial(jax.jit, static_argnames=("dims", "lr", "b1", "b2", "eps", "wd", "count", "interpret"))
 def slim_update_nd(p, g, m, v_red, *, dims: Tuple[int, ...], lr, b1=0.9, b2=0.95,
-                   eps=1e-8, wd=0.0, count=1, interpret=True):
+                   eps=1e-8, wd=0.0, count=1, interpret=None):
     """n-D params, any reduction-dims subset (the general SlimAdam spec).
 
     ``v_red`` keeps the reduced axes as size 1, matching
@@ -336,7 +329,7 @@ def slim_update_nd(p, g, m, v_red, *, dims: Tuple[int, ...], lr, b1=0.9, b2=0.95
 
 
 @functools.partial(jax.jit, static_argnames=("axis", "interpret"))
-def snr_partial_op(v, *, axis: int = 1, interpret=True):
+def snr_partial_op(v, *, axis: int = 1, interpret=None):
     """Per-line partial centered stats of a canonical moment view, flattened
     to 1-D: (line_sum, shifted_line_sum, shifted_line_sumsq, line_first).
 
@@ -353,7 +346,7 @@ def snr_partial_op(v, *, axis: int = 1, interpret=True):
 
 
 @functools.partial(jax.jit, static_argnames=("axis", "interpret"))
-def snr_op(v, *, axis: int = 1, interpret=True) -> jnp.ndarray:
+def snr_op(v, *, axis: int = 1, interpret=None) -> jnp.ndarray:
     """Scalar SNR over a canonical moment view via the fused centered-stats
     kernels (accurate for near-constant, high-SNR lines). ``v`` is 2-D
     (rows, cols) or batched 3-D (batch, rows, cols); ``axis`` is the

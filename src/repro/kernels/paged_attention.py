@@ -1,11 +1,16 @@
 """Ragged paged-attention Pallas kernel (decode + chunked prefill).
 
 The serving fast path stores each attention layer's KV cache as a pool of
-fixed-size *pages* in the fused layout ``(n_pages, page_size, 2 * kv_heads,
-head_dim)`` — K and V interleaved on even/odd head indices so one page DMA
-streams both (see :mod:`repro.serve.kvpool`). A request owns a row of a
-page *table* (``(B, max_pages) int32``; entry 0 is the reserved null page)
-and a ``lengths`` entry saying how many positions are live.
+fixed-size *pages* in the fused layout ``(n_pages, 2 * kv_heads, page_size,
+head_dim)`` — K and V of each kv head on adjacent (even/odd) head indices,
+so one page DMA streams both, and every head's K or V is a whole
+``(page_size, head_dim)`` tile. This module owns the layout:
+:func:`pool_shape` sizes a pool, :func:`pool_write` stores K/V into it, and
+the kernel and :func:`paged_attention_ref` read it; the page accounting is
+:mod:`repro.serve.kvpool`'s. A request
+owns a row of a page *table* (``(B, max_pages) int32``; entry 0 is the
+reserved null page) and a ``lengths`` entry saying how many positions are
+live.
 
 One kernel serves both serving phases:
 
@@ -15,6 +20,12 @@ One kernel serves both serving phases:
     tokens at absolute positions ``length - C .. length - 1``; the causal
     in-kernel mask (``k_abs <= q_abs``) subsumes the ragged length mask, so
     prefill costs ``ceil(S/C)`` steps instead of S decode steps.
+
+Inside the kernel the queries are grouped per kv head: the wrapper lays q
+out as ``(B, KV, C * rep, hd)`` (row ``c * rep + r`` is query position c,
+head ``kv * rep + r``), so each kv head is one ``(C * rep, hd) x (hd,
+page)`` matmul against its K tile and one ``(C * rep, page) x (page, hd)``
+against its V tile — leading-dim indexing only, no strided head split.
 
 Grid is ``(B, max_pages)`` with the page dim innermost and *sequential*, so
 Pallas double-buffers the page stream: the next page's DMA (its block index
@@ -41,29 +52,61 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ops import default_interpret
-from .tiling import COMPUTE_ITEMSIZE, VMEM_BUDGET
+from .tiling import COMPUTE_ITEMSIZE, VMEM_BUDGET, default_interpret
 
 NEG_INF = -1e30
 
 # Full-size VMEM blocks the kernel keeps live per instance: the current KV
 # page, the next page's double-buffer in flight, and one compute copy
-# (cast / exp scratch) — q and the accumulator are O(C * H * hd) and charged
-# separately by paged_fits.
+# (cast / exp scratch) — q, the accumulator and the softmax stats are
+# O(C * H) blocks charged separately by paged_fits.
 PAGED_ATTN_BUFS = 3
+
+
+def pool_shape(n_pages: int, page_size: int, n_kv_heads: int,
+               head_dim: int) -> tuple:
+    """Device array shape of one (unstacked) pool in the fused layout."""
+    return (n_pages, 2 * n_kv_heads, page_size, head_dim)
+
+
+def pool_write(pool: jnp.ndarray, table: jnp.ndarray, rows: jnp.ndarray,
+               positions: jnp.ndarray, valid: jnp.ndarray, k: jnp.ndarray,
+               v: jnp.ndarray) -> jnp.ndarray:
+    """Store the K/V of N positions into their pages and return the pool.
+
+    rows, positions, valid: (N,) — the table row each position belongs to,
+    its absolute position, and whether the write is live (dead writes go to
+    the null page 0, which no live row reads). k, v: (N, KV, hd).
+    """
+    page_size = pool.shape[2]
+    pidx = jnp.clip(positions // page_size, 0, table.shape[1] - 1)
+    page = jnp.where(valid, table[rows, pidx], 0)
+    n, kv, hd = k.shape
+    kv_rows = jnp.stack([k, v], axis=2).reshape(n, 2 * kv, hd)   # K even, V odd
+    return pool.at[page, :, positions % page_size].set(kv_rows.astype(pool.dtype))
+
+
+def _vmem_elems(*shape: int) -> int:
+    """Elements a VMEM block of ``shape`` occupies: its last two dims are
+    padded to the (8, 128) f32 tile."""
+    lead = math.prod(shape[:-2])
+    return lead * -(-shape[-2] // 8) * 8 * -(-shape[-1] // 128) * 128
 
 
 def paged_fits(chunk: int, n_heads: int, head_dim: int, page_size: int,
                kv2: int, *, itemsize: int = COMPUTE_ITEMSIZE) -> bool:
     """Whether one grid instance's working set fits :data:`VMEM_BUDGET`:
-    ``PAGED_ATTN_BUFS`` page lines (current + prefetched + compute copy)
-    plus the q block, the acc block and the two (C, H) softmax stats, all
-    charged at the f32 compute itemsize (same policy as
-    :func:`repro.kernels.tiling.strip_fits`)."""
-    page_line = page_size * kv2 * head_dim
-    qacc = chunk * n_heads * head_dim
-    stats = chunk * n_heads
-    working = PAGED_ATTN_BUFS * page_line + 2 * qacc + 2 * stats
+    ``PAGED_ATTN_BUFS`` page blocks (current + prefetched + compute copy)
+    plus the q and acc blocks ``(KV, C * rep, hd)`` and the two softmax
+    stats ``(KV, C * rep, 1)``, each at its (8, 128)-tile-padded footprint
+    (a stats column takes a full 128-lane tile) and the f32 compute
+    itemsize (same policy as :func:`repro.kernels.tiling.strip_fits`)."""
+    kv = kv2 // 2
+    cr = chunk * (n_heads // kv)
+    page_block = _vmem_elems(kv2, page_size, head_dim)
+    qacc = _vmem_elems(kv, cr, head_dim)
+    stats = _vmem_elems(kv, cr, 1)
+    working = PAGED_ATTN_BUFS * page_block + 2 * qacc + 2 * stats
     return working * itemsize <= VMEM_BUDGET
 
 
@@ -71,10 +114,9 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, pool_ref, acc_ref, m_ref, l_ref,
                   *, page: int, kv: int, rep: int):
     b = pl.program_id(0)
     p = pl.program_id(1)
-    c = q_ref.shape[1]
-    h = kv * rep
-    hd = q_ref.shape[3]
-    scale = 1.0 / math.sqrt(hd)
+    cr = q_ref.shape[2]                 # C * rep query rows per kv head
+    c = cr // rep
+    scale = 1.0 / math.sqrt(q_ref.shape[3])
 
     @pl.when(p == 0)
     def _init():
@@ -87,31 +129,30 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, pool_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(p < n_pages)
     def _page():
-        kv_blk = pool_ref[...].astype(jnp.float32)   # (1, page, 2KV, hd)
-        k = kv_blk[0, :, 0::2, :]                    # (page, KV, hd)
-        v = kv_blk[0, :, 1::2, :]
-        q = q_ref[...].astype(jnp.float32)           # (1, C, H, hd)
-        qg = q[0].reshape(c, kv, rep, hd) * scale
-        s = jnp.einsum("ckrd,pkd->ckrp", qg, k)      # (C, KV, rep, page)
-
         # Causal/ragged mask in absolute positions: queries sit at the last
-        # C positions of the row, so k <= q also bounds k < length.
-        k_abs = p * page + jnp.arange(page)
-        q_abs = length - c + jnp.arange(c)
-        mask = k_abs[None, :] <= q_abs[:, None]      # (C, page)
-        s = jnp.where(mask[:, None, None, :], s, NEG_INF)
-
-        s_flat = s.reshape(1, c, h, page)
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(s_flat, axis=-1))
-        pexp = jnp.exp(s_flat - m_new[..., None])
-        pexp = jnp.where(mask[None, :, None, :], pexp, 0.0)
-        corr = jnp.exp(m_old - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=-1)
-        pv = jnp.einsum("ckrp,pkd->ckrd",
-                        pexp[0].reshape(c, kv, rep, page), v)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + pv.reshape(1, c, h, hd)
-        m_ref[...] = m_new
+        # C positions of the row, so k <= q also bounds k < length. Row
+        # c * rep + r holds query c, and c >= x  <=>  row >= x * rep, so the
+        # mask needs no integer division.
+        row = jax.lax.broadcasted_iota(jnp.int32, (cr, page), 0)
+        k_abs = p * page + jax.lax.broadcasted_iota(jnp.int32, (cr, page), 1)
+        mask = row >= (k_abs - (length - c)) * rep          # (C*rep, page)
+        for j in range(kv):
+            k = pool_ref[0, 2 * j].astype(jnp.float32)       # (page, hd)
+            v = pool_ref[0, 2 * j + 1].astype(jnp.float32)
+            q = q_ref[0, j].astype(jnp.float32) * scale      # (C*rep, hd)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, NEG_INF)
+            m_old = m_ref[0, j]                              # (C*rep, 1)
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+            pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_old - m_new)
+            l_ref[0, j] = l_ref[0, j] * corr + jnp.sum(pexp, axis=-1,
+                                                       keepdims=True)
+            pv = jax.lax.dot_general(pexp, v, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_ref[0, j] = acc_ref[0, j] * corr + pv
+            m_ref[0, j] = m_new
 
 
 def paged_attention_ref(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
@@ -121,13 +162,17 @@ def paged_attention_ref(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
     used when :func:`paged_fits` rejects the geometry and as the parity
     oracle in tests."""
     b, c, h, hd = q.shape
-    _, page, kv2, _ = pool.shape
+    _, kv2, page, _ = pool.shape
     kv = kv2 // 2
     rep = h // kv
-    gathered = pool[table].astype(jnp.float32)   # (B, max_pages, page, 2KV, hd)
+    gathered = pool[table].astype(jnp.float32)   # (B, max_pages, 2KV, page, hd)
     s_max = table.shape[1] * page
-    k = gathered[:, :, :, 0::2, :].reshape(b, s_max, kv, hd)
-    v = gathered[:, :, :, 1::2, :].reshape(b, s_max, kv, hd)
+
+    def heads(x):   # (B, max_pages, KV, page, hd) -> (B, S, KV, hd)
+        return jnp.swapaxes(x, 2, 3).reshape(b, s_max, kv, hd)
+
+    k = heads(gathered[:, :, 0::2])
+    v = heads(gathered[:, :, 1::2])
     qg = q.astype(jnp.float32).reshape(b, c, kv, rep, hd) / math.sqrt(hd)
     s = jnp.einsum("bckrd,bpkd->bckrp", qg, k)
     q_abs = lengths[:, None] - c + jnp.arange(c)[None, :]      # (B, C)
@@ -148,7 +193,7 @@ def paged_attention(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
 
     q: (B, C, H, hd) — C == 1 for decode, C == chunk for chunked prefill
     (queries at absolute positions ``lengths - C .. lengths - 1``).
-    pool: (n_pages, page, 2 * KV, hd), K/V on even/odd head indices.
+    pool: (n_pages, 2 * KV, page, hd), K/V on even/odd head indices.
     table: (B, max_pages) int32 page ids (0 = null page for padding).
     lengths: (B,) int32 live positions per row (0 = inactive row -> zeros).
 
@@ -158,7 +203,7 @@ def paged_attention(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
     retraces through the reference when a kernel launch fails mid-serve.
     """
     b, c, h, hd = q.shape
-    _, page, kv2, hd2 = pool.shape
+    _, kv2, page, hd2 = pool.shape
     assert hd2 == hd and kv2 % 2 == 0, (pool.shape, q.shape)
     kv = kv2 // 2
     assert h % kv == 0, (h, kv)
@@ -167,6 +212,9 @@ def paged_attention(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
     if use_ref or not paged_fits(c, h, hd, page, kv2):
         return paged_attention_ref(q, pool, table, lengths)
 
+    cr = c * rep
+    # (B, C, KV, rep, hd) -> (B, KV, C * rep, hd): row c * rep + r per kv head
+    qg = jnp.swapaxes(q.reshape(b, c, kv, rep, hd), 1, 2).reshape(b, kv, cr, hd)
     kernel = functools.partial(_paged_kernel, page=page, kv=kv, rep=rep)
     f32 = jnp.float32
     acc, m, l = pl.pallas_call(
@@ -175,22 +223,23 @@ def paged_attention(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
             num_scalar_prefetch=2,
             grid=(b, max_pages),
             in_specs=[
-                pl.BlockSpec((1, c, h, hd), lambda bi, p, tbl, ln: (bi, 0, 0, 0)),
-                pl.BlockSpec((1, page, kv2, hd),
+                pl.BlockSpec((1, kv, cr, hd), lambda bi, p, tbl, ln: (bi, 0, 0, 0)),
+                pl.BlockSpec((1, kv2, page, hd),
                              lambda bi, p, tbl, ln: (tbl[bi, p], 0, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, c, h, hd), lambda bi, p, tbl, ln: (bi, 0, 0, 0)),
-                pl.BlockSpec((1, c, h), lambda bi, p, tbl, ln: (bi, 0, 0)),
-                pl.BlockSpec((1, c, h), lambda bi, p, tbl, ln: (bi, 0, 0)),
+                pl.BlockSpec((1, kv, cr, hd), lambda bi, p, tbl, ln: (bi, 0, 0, 0)),
+                pl.BlockSpec((1, kv, cr, 1), lambda bi, p, tbl, ln: (bi, 0, 0, 0)),
+                pl.BlockSpec((1, kv, cr, 1), lambda bi, p, tbl, ln: (bi, 0, 0, 0)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, c, h, hd), f32),
-            jax.ShapeDtypeStruct((b, c, h), f32),
-            jax.ShapeDtypeStruct((b, c, h), f32),
+            jax.ShapeDtypeStruct((b, kv, cr, hd), f32),
+            jax.ShapeDtypeStruct((b, kv, cr, 1), f32),
+            jax.ShapeDtypeStruct((b, kv, cr, 1), f32),
         ],
-        interpret=default_interpret() if interpret is None else interpret,
-    )(table, lengths, q, pool)
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
+        interpret=default_interpret(interpret),
+    )(table, lengths, qg, pool)
+    out = acc / jnp.maximum(l, 1e-30)
+    out = jnp.swapaxes(out.reshape(b, kv, c, rep, hd), 1, 2).reshape(b, c, h, hd)
     return out.astype(q.dtype)

@@ -39,7 +39,7 @@ from jax.experimental import pallas as pl
 
 from .fused_adam import bias_corrections, health_terms
 from .snr_stats import centered_line_stats
-from .tiling import pad_kept, strip_grid, trim_kept
+from .tiling import default_interpret, pad_kept, strip_grid, trim_kept
 
 # Live full-size fp32 buffers per kernel instance (inputs + outputs + cast
 # headroom) — the n_bufs VMEM-fitting argument for each form. Dispatchers
@@ -74,7 +74,7 @@ def _slim_kernel(p_ref, g_ref, m_ref, v_ref, scal_ref, p_out, m_out, v_out,
 def slim_update_batched(p, g, m, v_line, *, axis: int, lr: float, b1: float = 0.9,
                         b2: float = 0.95, eps: float = 1e-8, wd: float = 0.0,
                         count=1, block: Optional[int] = None,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """Batched SlimAdam step on the (B, R, C) canonical form.
 
     p, g, m: (B, R, C); v_line: (B, R, 1) fp32 (axis=1, reduce over C) or
@@ -111,7 +111,7 @@ def slim_update_batched(p, g, m, v_line, *, axis: int, lr: float, b1: float = 0.
             jax.ShapeDtypeStruct((b, r, c), jnp.float32),
             jax.ShapeDtypeStruct(v_shape, jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(p, g, m, v_line, scal)
 
 
@@ -154,7 +154,7 @@ def _slim_precond_kernel(g_ref, m_ref, v_ref, scal_ref, u_out, m_out, v_out,
 def slim_precond_batched(g, m, v_line, *, axis: int, b1: float = 0.9,
                          b2: float = 0.95, eps: float = 1e-8, count=1,
                          with_snr: bool = False, with_health: bool = False,
-                         block: Optional[int] = None, interpret: bool = True):
+                         block: Optional[int] = None, interpret: Optional[bool] = None):
     """Preconditioned batched SlimAdam update: (g, m, v_line) -> (u, m', v').
 
     The GradientTransformation form of :func:`slim_update_batched` — no
@@ -211,7 +211,7 @@ def slim_precond_batched(g, m, v_line, *, axis: int, b1: float = 0.9,
                   pl.BlockSpec((2,), lambda bi, i: (0,))],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(g, m, v_line, scal)
 
 
@@ -260,7 +260,7 @@ def _slim_partial_kernel(g_ref, m_ref, m_out, part_out, *extra_outs, b1: float,
 def slim_partial_stats_batched(g, m, *, axis: int, b1: float = 0.9,
                                with_snr: bool = False, with_health: bool = False,
                                block: Optional[int] = None,
-                               interpret: bool = True):
+                               interpret: Optional[bool] = None):
     """Pass 1 of the sharded psum regime on the (B, R, C) canonical form.
 
     g, m: (B, R, C). Returns ``(m_new, part)`` — m_new fp32 full shape, part
@@ -307,7 +307,7 @@ def slim_partial_stats_batched(g, m, *, axis: int, b1: float = 0.9,
         in_specs=[sg.full, sg.full],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(g, m)
 
 
@@ -328,7 +328,7 @@ def _slim_apply_line_kernel(m_ref, v_ref, scal_ref, u_out, *, eps: float):
 
 def slim_finalize_batched(m_new, v_line, *, axis: int, ek=None, b1: float = 0.9,
                           b2: float = 0.95, eps: float = 1e-8, count=1,
-                          block: Optional[int] = None, interpret: bool = True):
+                          block: Optional[int] = None, interpret: Optional[bool] = None):
     """Pass 2 of the sharded psum regime (post-psum finalize).
 
     m_new: (B, R, C) fp32 from :func:`slim_partial_stats_batched`. With
@@ -368,7 +368,7 @@ def slim_finalize_batched(m_new, v_line, *, axis: int, ek=None, b1: float = 0.9,
             in_specs=[sg.full, sg.line, pl.BlockSpec((2,), lambda bi, i: (0,))],
             out_specs=[sg.full],
             out_shape=[jax.ShapeDtypeStruct((b, r, c), jnp.float32)],
-            interpret=interpret,
+            interpret=default_interpret(interpret),
         )(m_new, v_line, scal)[0]
     kernel = functools.partial(_slim_finalize_kernel, b2=b2, eps=eps)
     return pl.pallas_call(
@@ -379,7 +379,7 @@ def slim_finalize_batched(m_new, v_line, *, axis: int, ek=None, b1: float = 0.9,
         out_specs=[sg.full, sg.line],
         out_shape=[jax.ShapeDtypeStruct((b, r, c), jnp.float32),
                    jax.ShapeDtypeStruct(line_shape, jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(m_new, v_line, ek, scal)
 
 
@@ -398,7 +398,7 @@ def _unb1(outs):
 
 def slim_update(p, g, m, v_row, *, lr: float, b1: float = 0.9, b2: float = 0.95,
                 eps: float = 1e-8, wd: float = 0.0, count=1,
-                row_block: int = 32, interpret: bool = True):
+                row_block: int = 32, interpret: Optional[bool] = None):
     """p, g, m: (R, C); v_row: (R, 1) fp32 reduced moment. Returns (p', m', v')."""
     return _unb1(slim_update_batched(*_b1(p, g, m, v_row), axis=1, lr=lr, b1=b1,
                                      b2=b2, eps=eps, wd=wd, count=count,
@@ -407,7 +407,7 @@ def slim_update(p, g, m, v_row, *, lr: float, b1: float = 0.9, b2: float = 0.95,
 
 def slim_update_major(p, g, m, v_col, *, lr: float, b1: float = 0.9, b2: float = 0.95,
                       eps: float = 1e-8, wd: float = 0.0, count=1,
-                      col_block: int = 256, interpret: bool = True):
+                      col_block: int = 256, interpret: Optional[bool] = None):
     """p, g, m: (R, C); v_col: (1, C) fp32 moment reduced over rows.
     Returns (p', m', v')."""
     return _unb1(slim_update_batched(*_b1(p, g, m, v_col), axis=0, lr=lr, b1=b1,
@@ -417,7 +417,7 @@ def slim_update_major(p, g, m, v_col, *, lr: float, b1: float = 0.9, b2: float =
 
 def slim_precond(g, m, v_row, *, b1: float = 0.9, b2: float = 0.95,
                  eps: float = 1e-8, count=1, row_block: int = 32,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """Preconditioned SlimAdam update only: (g, m, v_row) -> (u, m', v_row').
 
     g, m: (R, C); v_row: (R, 1) fp32 reduced moment; u is fp32 full-shape.
@@ -429,7 +429,7 @@ def slim_precond(g, m, v_row, *, b1: float = 0.9, b2: float = 0.95,
 
 def slim_precond_major(g, m, v_col, *, b1: float = 0.9, b2: float = 0.95,
                        eps: float = 1e-8, count=1, col_block: int = 256,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Preconditioned major-axis SlimAdam update: (g, m, v_col) -> (u, m', v').
 
     g, m: (R, C); v_col: (1, C) fp32 moment reduced over rows; u is fp32
@@ -442,7 +442,7 @@ def slim_precond_major(g, m, v_col, *, b1: float = 0.9, b2: float = 0.95,
 
 def slim_partial_stats(g, m, *, axis: int = 1, b1: float = 0.9,
                        with_snr: bool = False, block: Optional[int] = None,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """2-D wrapper of :func:`slim_partial_stats_batched`: g, m (R, C) ->
     (m_new, part[, s1c, s2c, first]); lines are (R, 1) (axis=1) / (1, C)
     (axis=0)."""
@@ -453,7 +453,7 @@ def slim_partial_stats(g, m, *, axis: int = 1, b1: float = 0.9,
 
 def slim_finalize(m_new, v_line, *, axis: int = 1, ek=None, b1: float = 0.9,
                   b2: float = 0.95, eps: float = 1e-8, count=1,
-                  block: Optional[int] = None, interpret: bool = True):
+                  block: Optional[int] = None, interpret: Optional[bool] = None):
     """2-D wrapper of :func:`slim_finalize_batched`: m_new (R, C) + lines ->
     (u, v_new) with ``ek``, or just u when ``v_line`` is already the
     completed new moment (owner-sharded flow)."""

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .tiling import pad_kept, strip_grid
+from .tiling import default_interpret, pad_kept, strip_grid
 
 # Live full-size fp32 buffers per instance (the n_bufs VMEM-fitting
 # argument): input + cast copy (+ shifted copy for the centered form).
@@ -100,7 +100,7 @@ def _snr_centered_partial_kernel(v_ref, s1_out, s1c_out, s2c_out, f_out, *, red_
 
 
 def _stats_call(v, *, axis: int, n_bufs: int, n_outs: int, kernel_body,
-                block: Optional[int], interpret: bool):
+                block: Optional[int], interpret: Optional[bool]):
     """Shared pad-fit-launch path for both stats flavors. Returns ``n_outs``
     arrays of shape (B, kept)."""
     assert v.ndim == 3 and axis in (0, 1)
@@ -119,19 +119,19 @@ def _stats_call(v, *, axis: int, n_bufs: int, n_outs: int, kernel_body,
         in_specs=[sg.full],
         out_specs=[sg.stat] * n_outs,
         out_shape=[jax.ShapeDtypeStruct((b, sg.kept), jnp.float32)] * n_outs,
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(v)
 
 
 def snr_stats_batched(v, *, axis: int, block: Optional[int] = None,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """v: (B, R, C) -> (line_sum, line_sumsq), each (B, kept)."""
     return _stats_call(v, axis=axis, n_bufs=STATS_BUFS, n_outs=2,
                        kernel_body=_snr_kernel, block=block, interpret=interpret)
 
 
 def snr_stats_centered_batched(v, *, axis: int, block: Optional[int] = None,
-                               interpret: bool = True):
+                               interpret: Optional[bool] = None):
     """v: (B, R, C) -> (line_sum, shifted_line_sum, shifted_line_sumsq),
     each (B, kept).
 
@@ -150,7 +150,7 @@ def snr_stats_centered_batched(v, *, axis: int, block: Optional[int] = None,
 
 
 def snr_stats_centered_partial_batched(v, *, axis: int, block: Optional[int] = None,
-                                       interpret: bool = True):
+                                       interpret: Optional[bool] = None):
     """v: (B, R, C) -> (line_sum, shifted_line_sum, shifted_line_sumsq,
     line_first), each (B, kept) — the partial-sums entry point for sharded
     reduction lines.
@@ -175,13 +175,13 @@ def snr_stats_centered_partial_batched(v, *, axis: int, block: Optional[int] = N
 # ---------------------------------------------------------------------------
 
 
-def snr_stats(v, *, row_block: int = 64, interpret: bool = True):
+def snr_stats(v, *, row_block: int = 64, interpret: Optional[bool] = None):
     """v: (R, C) -> (row_sum (R,), row_sumsq (R,))."""
     s1, s2 = snr_stats_batched(v[None], axis=1, block=row_block, interpret=interpret)
     return s1[0], s2[0]
 
 
-def snr_stats_centered(v, *, row_block: int = 64, interpret: bool = True):
+def snr_stats_centered(v, *, row_block: int = 64, interpret: Optional[bool] = None):
     """v: (R, C) -> (row_sum, shifted_row_sum, shifted_row_sumsq), all (R,).
     See :func:`snr_stats_centered_batched` for the shift-centering argument."""
     s1, s1c, s2c = snr_stats_centered_batched(v[None], axis=1, block=row_block,
@@ -189,7 +189,7 @@ def snr_stats_centered(v, *, row_block: int = 64, interpret: bool = True):
     return s1[0], s1c[0], s2c[0]
 
 
-def snr_stats_centered_partial(v, *, row_block: int = 64, interpret: bool = True):
+def snr_stats_centered_partial(v, *, row_block: int = 64, interpret: Optional[bool] = None):
     """v: (R, C) -> (row_sum, shifted_row_sum, shifted_row_sumsq, row_first),
     all (R,). B=1 wrapper over the partial-sums entry point."""
     s1, s1c, s2c, f = snr_stats_centered_partial_batched(
@@ -197,7 +197,7 @@ def snr_stats_centered_partial(v, *, row_block: int = 64, interpret: bool = True
     return s1[0], s1c[0], s2c[0], f[0]
 
 
-def snr_stats_centered_major(v, *, col_block: int = 256, interpret: bool = True):
+def snr_stats_centered_major(v, *, col_block: int = 256, interpret: Optional[bool] = None):
     """v: (R, C) -> (col_sum, shifted_col_sum, shifted_col_sumsq), all (C,).
     Major-axis twin of :func:`snr_stats_centered` — the reduction runs over
     sublanes, so a moment whose compression dims are leading gets its

@@ -18,11 +18,14 @@ on TPU, so the scratch state carries across the chunk steps of one
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .tiling import default_interpret
 
 
 def _ssm_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, dskip_ref, h0_ref,
@@ -56,7 +59,7 @@ def _ssm_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, dskip_ref, h0_ref,
 
 
 def ssm_scan(x, dt, a, b_t, c_t, d_skip, h0, *, chunk: int = 512,
-             d_tile: int = 256, interpret: bool = True):
+             d_tile: int = 256, interpret: Optional[bool] = None):
     """x, dt: (B, S, D); a: (D, N); b_t, c_t: (B, S, N); h0: (B, D, N).
 
     Returns (y (B, S, D) fp32, h_final (B, D, N) fp32). Semantics match
@@ -95,5 +98,5 @@ def ssm_scan(x, dt, a, b_t, c_t, d_skip, h0, *, chunk: int = 512,
             jax.ShapeDtypeStruct((bsz, d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d_tile, n), jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(x, dt, a, b_t, c_t, d_skip, h0)

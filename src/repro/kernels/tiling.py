@@ -23,8 +23,9 @@ seen in interpret mode, so the bound lives here rather than in CI.
 """
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
@@ -41,6 +42,17 @@ VMEM_BUDGET = 8 << 20
 # explicitly; `repro.analysis.kernelcheck` verifies the f32-compute contract
 # (and the resulting footprint bound) statically for every registered kernel.
 COMPUTE_ITEMSIZE = 4
+
+
+def default_interpret(interpret: Optional[bool] = None) -> bool:
+    """The interpret flag a kernel entry runs with: the caller's when given,
+    otherwise Pallas interpret mode everywhere except a real TPU backend
+    (where the compiled kernel is the point; elsewhere the interpreter is
+    the correctness harness). Every kernel entry defaults to ``None``, so no
+    kernel runs interpreted on a TPU unless its caller asks for it."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"
 
 
 def fit_strip_block(red_size: int, block: int, kept_size: int, n_bufs: int,

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
-
 """Multi-pod dry-run: prove every (arch x shape x mesh) cell lowers, SPMD-
 partitions, compiles, and fits — then extract the roofline terms.
 
@@ -10,7 +7,14 @@ partitions, compiles, and fits — then extract the roofline terms.
 Emits a JSON record (memory analysis, loop-corrected HLO stats, roofline
 terms) to benchmarks/results/dryrun/. The 512 placeholder host devices exist
 only in this process — tests and benchmarks see the real single CPU device.
+The tool is CPU-only: it pins JAX to the CPU platform before JAX starts, so
+on a machine with a TPU it never takes the chip (and the sweep's one child
+per cell never contends for it).
 """
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
 import argparse
 import dataclasses
 import json
@@ -23,6 +27,9 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+# Also holds when JAX was imported before this module set the environment.
+jax.config.update("jax_platforms", "cpu")
 
 from ..configs import (
     ARCH_IDS,

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,10 +28,74 @@ from ..sharding.state_shardings import opt_state_specs
 from ..train.guard import ROLLBACK, Guard, GuardConfig
 from ..train.step import make_train_step
 from ..train.trainer import _SLIM_FAMILY, make_optimizer
+from .compile_cache import enable_compile_cache
 from .mesh import make_production_mesh
 
 
+class TrainSetup(NamedTuple):
+    """What :func:`build_train_setup` returns. Trace and call ``step_fn``
+    inside ``use_sharding(ctx)``: the model's sharding constraints resolve
+    against the active context when the step is traced."""
+
+    ctx: Optional[ShardingContext]
+    params: Any
+    opt_state: Any
+    step_fn: Callable
+
+
+def build_train_setup(cfg, optimizer: str, lr, *, backend: str, mesh=None,
+                      batch: int, seq: int, guard: bool = False,
+                      grad_accum: int = 1, seed: int = 0) -> TrainSetup:
+    """Parameters, optimizer state and the jitted train step of ``cfg``.
+
+    With a ``mesh`` the state is laid out by the logical rule table
+    (FSDP x TP), the fused backend runs its tree update under shard_map on
+    the local shards, and psum-regime reduced moments keep their
+    owner-slice layout across the step boundary. With ``mesh=None``
+    everything lives on one device."""
+    ctx = (ShardingContext(mesh, rules=dict(cfg.sharding_overrides) or None)
+           if mesh is not None else None)
+    with use_sharding(ctx):
+        params, meta = cfg.init(jax.random.PRNGKey(seed))
+        # Specs first: the fused backend wants mesh + param specs at
+        # construction so its tree update runs under shard_map on the shards.
+        p_specs = param_specs(meta, params) if ctx is not None else None
+        emit_health = guard and optimizer in ("adam",) + _SLIM_FAMILY
+        tx = make_optimizer(optimizer, lr, params, meta, backend=backend,
+                            mesh=mesh, param_specs=p_specs,
+                            emit_health=emit_health)
+        opt_state = tx.init(params)
+        if ctx is None:
+            step_fn = jax.jit(make_train_step(cfg, tx, grad_accum=grad_accum,
+                                              guard=guard))
+            return TrainSetup(None, params, opt_state, step_fn)
+
+        from ..optim.base import resolve_backend
+
+        p_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), p_specs,
+                            is_leaf=lambda x: isinstance(x, P))
+        # Fused backend: pin psum-regime reduced moments to their
+        # owner-slice storage layout so the pjit state boundary matches
+        # the shard_map output (no per-step O(kept) re-gather).
+        owner_mesh = mesh if resolve_backend(backend) == "fused" else None
+        o_specs = opt_state_specs(jax.eval_shape(lambda: opt_state), params,
+                                  p_specs, owner_mesh=owner_mesh)
+        o_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), o_specs,
+                            is_leaf=lambda x: isinstance(x, P))
+        params = jax.device_put(params, p_sh)
+        opt_state = jax.device_put(opt_state, o_sh)
+        b_sh = NamedSharding(mesh, ctx.spec_for(("batch", None), (batch, seq)))
+        batch_sh = {"tokens": b_sh, "labels": b_sh}
+        in_sh = (p_sh, o_sh, batch_sh, None) if guard else (p_sh, o_sh, batch_sh)
+        step_fn = jax.jit(make_train_step(cfg, tx, grad_accum=grad_accum,
+                                          grad_shardings=p_sh, guard=guard),
+                          in_shardings=in_sh,
+                          out_shardings=(p_sh, o_sh, None), donate_argnums=(0, 1))
+        return TrainSetup(ctx, params, opt_state, step_fn)
+
+
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="smollm_135m")
     ap.add_argument("--reduced", action="store_true", help="CPU-scale config")
@@ -54,46 +119,12 @@ def main(argv=None):
 
     cfg = get_reduced(args.arch) if args.reduced or args.mesh == "none" else get_config(args.arch)
     mesh = None if args.mesh == "none" else make_production_mesh(multi_pod=(args.mesh == "multi"))
-    ctx = ShardingContext(mesh, rules=dict(cfg.sharding_overrides) or None) if mesh else None
+    setup = build_train_setup(cfg, args.optimizer, args.lr, backend=args.backend,
+                              mesh=mesh, batch=args.batch, seq=args.seq,
+                              guard=args.guard, grad_accum=args.grad_accum)
+    params, opt_state, step_fn = setup.params, setup.opt_state, setup.step_fn
 
-    with use_sharding(ctx):
-        params, meta = cfg.init(jax.random.PRNGKey(0))
-        # Specs first: the fused backend wants mesh + param specs at
-        # construction so its tree update runs under shard_map on the shards.
-        p_specs = param_specs(meta, params) if ctx is not None else None
-        emit_health = args.guard and args.optimizer in ("adam",) + _SLIM_FAMILY
-        tx = make_optimizer(args.optimizer, args.lr, params, meta,
-                            backend=args.backend, mesh=mesh, param_specs=p_specs,
-                            emit_health=emit_health)
-        opt_state = tx.init(params)
-
-        if ctx is not None:
-            from ..optim.base import resolve_backend
-
-            p_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), p_specs,
-                                is_leaf=lambda x: isinstance(x, P))
-            # Fused backend: pin psum-regime reduced moments to their
-            # owner-slice storage layout so the pjit state boundary matches
-            # the shard_map output (no per-step O(kept) re-gather).
-            owner_mesh = mesh if resolve_backend(args.backend) == "fused" else None
-            o_specs = opt_state_specs(jax.eval_shape(lambda: opt_state), params,
-                                      p_specs, owner_mesh=owner_mesh)
-            o_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), o_specs,
-                                is_leaf=lambda x: isinstance(x, P))
-            params = jax.device_put(params, p_sh)
-            opt_state = jax.device_put(opt_state, o_sh)
-            b_sh = NamedSharding(mesh, ctx.spec_for(("batch", None), (args.batch, args.seq)))
-            batch_sh = {"tokens": b_sh, "labels": b_sh}
-            in_sh = ((p_sh, o_sh, batch_sh, None) if args.guard
-                     else (p_sh, o_sh, batch_sh))
-            step_fn = jax.jit(make_train_step(cfg, tx, grad_accum=args.grad_accum,
-                                              grad_shardings=p_sh, guard=args.guard),
-                              in_shardings=in_sh,
-                              out_shardings=(p_sh, o_sh, None), donate_argnums=(0, 1))
-        else:
-            step_fn = jax.jit(make_train_step(cfg, tx, grad_accum=args.grad_accum,
-                                              guard=args.guard))
-
+    with use_sharding(setup.ctx):
         start = 0
         if args.ckpt and store.latest_step(args.ckpt) is not None:
             state, extra = store.restore(args.ckpt, {"params": params, "opt": opt_state})

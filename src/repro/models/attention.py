@@ -17,7 +17,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..sharding.logical import constrain, shard_map
+from ..sharding.logical import constrain
 from .common import ParamSpec, apply_rotary, rotary_embedding, zeros_init
 
 NEG_INF = -1e30
@@ -316,7 +316,7 @@ def _attention_explicit_tp(p, x: jnp.ndarray, cfg: AttnConfig):
         return jax.lax.psum_scatter(y_part, "model", scatter_dimension=1, tiled=True)
 
     kvspec = P(None, "model", None) if kv_sharded else P(None, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(None, "model", None), kvspec, kvspec, P("model", None, None)),
         out_specs=xspec,
@@ -394,18 +394,11 @@ def _quantize_kv(x: jnp.ndarray):
 
 
 # ---------------------------------------------------------------------------
-# Paged serving path: KV lives in a page pool (repro.serve.kvpool layout:
-# (n_pages, page_size, 2*KV, hd), K/V interleaved on even/odd head indices),
-# addressed through a per-request page table. Decode and chunked prefill both
-# reduce through the same ragged Pallas kernel.
+# Paged serving path: KV lives in a page pool (layout owned by
+# repro.kernels.paged_attention), addressed through a per-request page table.
+# Decode and chunked prefill both reduce through the same ragged Pallas
+# kernel.
 # ---------------------------------------------------------------------------
-
-
-def _fused_kv_rows(k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
-    """k, v: (N, KV, hd) -> (N, 2*KV, hd) with K on even / V on odd head
-    indices — one scatter writes both halves of a page row."""
-    n, kv, hd = k.shape
-    return jnp.stack([k, v], axis=2).reshape(n, 2 * kv, hd)
 
 
 def attention_paged_decode(p, x: jnp.ndarray, pool: jnp.ndarray,
@@ -413,13 +406,13 @@ def attention_paged_decode(p, x: jnp.ndarray, pool: jnp.ndarray,
                            active: jnp.ndarray, cfg: AttnConfig,
                            *, interpret=None,
                            use_ref=False) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One-token paged decode. x: (B, 1, D); pool: (pages, P, 2KV, hd);
+    """One-token paged decode. x: (B, 1, D); pool: (pages, 2KV, P, hd);
     table: (B, max_pages); lengths: (B,) positions already stored. Writes the
     new token's K/V at position ``lengths`` (inactive rows are routed to the
     reserved null page 0, which no table entry of a live row ever points at),
     then attends over ``lengths + 1`` positions. Returns (y (B, 1, D), pool).
     """
-    from ..kernels.paged_attention import paged_attention
+    from ..kernels.paged_attention import paged_attention, pool_write
 
     b = x.shape[0]
     pos = lengths
@@ -428,10 +421,8 @@ def attention_paged_decode(p, x: jnp.ndarray, pool: jnp.ndarray,
         rope_sincos = rotary_embedding(pos[:, None], cfg.head_dim, cfg.rope_base)
     q, k_new, v_new = _project_qkv(p, x, rope_sincos, None)
 
-    page_size = pool.shape[1]
-    page = jnp.where(active, table[jnp.arange(b), pos // page_size], 0)
-    kv_rows = _fused_kv_rows(k_new[:, 0], v_new[:, 0])
-    pool = pool.at[page, pos % page_size].set(kv_rows.astype(pool.dtype))
+    pool = pool_write(pool, table, jnp.arange(b), pos, active,
+                      k_new[:, 0], v_new[:, 0])
 
     kv_len = jnp.where(active, pos + 1, 0).astype(jnp.int32)
     out = paged_attention(q, pool, table, kv_len, interpret=interpret,
@@ -452,7 +443,7 @@ def attention_paged_prefill(p, x: jnp.ndarray, pool: jnp.ndarray,
     caller samples at chunk index ``n_valid - 1``). The in-kernel causal mask
     ``k_abs <= q_abs`` keeps every *valid* query's reduction inside the
     row's live pages. Returns (y (1, C, D), pool)."""
-    from ..kernels.paged_attention import paged_attention
+    from ..kernels.paged_attention import paged_attention, pool_write
 
     c = x.shape[1]
     positions = pos0 + jnp.arange(c)
@@ -461,13 +452,9 @@ def attention_paged_prefill(p, x: jnp.ndarray, pool: jnp.ndarray,
         rope_sincos = rotary_embedding(positions, cfg.head_dim, cfg.rope_base)
     q, k_new, v_new = _project_qkv(p, x, rope_sincos, None)
 
-    page_size = pool.shape[1]
-    max_pages = table_row.shape[1]
-    pidx = jnp.clip(positions // page_size, 0, max_pages - 1)
     valid = jnp.arange(c) < n_valid
-    page = jnp.where(valid, table_row[0, pidx], 0)
-    kv_rows = _fused_kv_rows(k_new[0], v_new[0])
-    pool = pool.at[page, positions % page_size].set(kv_rows.astype(pool.dtype))
+    pool = pool_write(pool, table_row, jnp.zeros(c, jnp.int32), positions,
+                      valid, k_new[0], v_new[0])
 
     kv_len = jnp.asarray(pos0 + c, jnp.int32)[None]
     out = paged_attention(q, pool, table_row, kv_len, interpret=interpret,

@@ -17,7 +17,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..sharding.logical import constrain, shard_map
+from ..sharding.logical import constrain
 from .common import ParamSpec
 
 
@@ -100,7 +100,7 @@ def _mlp_explicit_tp(p, x: jnp.ndarray, *, gated: bool):
         return jax.lax.psum_scatter(y_part, "model", scatter_dimension=1, tiled=True)
 
     wg = p.get("w_gate", p["w_up"])
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, wspec_col, wspec_row, wspec_col),
         out_specs=xspec,
@@ -180,7 +180,7 @@ def _expert_ffn_sharded(p, xg, cfg: MoEConfig, dtype):
     wspec = P("model", None, None)
     weights = {k: p[k] for k in ("w_up", "w_down") + (("w_gate",) if cfg.gated else ())}
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, {k: wspec for k in weights}),
         out_specs=xspec,

@@ -18,7 +18,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..sharding.logical import constrain, shard_map
+from ..sharding.logical import constrain
 from .common import ParamSpec, constant_init, normal_init, ones_init, zeros_init
 
 
@@ -370,7 +370,7 @@ def _ssm_explicit_tp(p, x: jnp.ndarray, cfg: SSMConfig):
     zw = zw.reshape(d, tp, di_l)
     w["in_proj"] = jnp.concatenate([xw, zw], axis=2).reshape(d, 2 * di)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, {k: wspecs[k] for k in w}),
         out_specs=xspec,

@@ -20,6 +20,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.paged_attention import pool_shape
 from ..sharding.logical import constrain
 from .attention import (
     AttnConfig,
@@ -396,7 +397,7 @@ def decode_step(cfg: ModelConfig, params, cache: DecodeCache, tokens: jnp.ndarra
 class PagedState(NamedTuple):
     """Device state for the paged decode path.
 
-    pools:   {'slot_i': (n_periods, n_pages, page, 2*KV, hd)} per attn slot
+    pools:   {'slot_i': (n_periods, n_pages, 2*KV, page, hd)} per attn slot
     table:   (B, max_pages) int32 page ids; entry 0 = reserved null page
     lengths: (B,) int32 positions already stored per batch row
     active:  (B,) bool — inactive rows write to the null page and attend
@@ -427,7 +428,8 @@ def init_paged_pools(cfg: ModelConfig, n_pages: int, page_size: int,
     for i, slot in enumerate(cfg.pattern):
         if slot.mixer == "attn":
             pools[f"slot_{i}"] = jnp.zeros(
-                (cfg.n_periods, n_pages, page_size, 2 * cfg.n_kv_heads, cfg.hd),
+                (cfg.n_periods,) + pool_shape(n_pages, page_size,
+                                              cfg.n_kv_heads, cfg.hd),
                 dtype)
     return pools
 

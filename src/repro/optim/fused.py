@@ -895,7 +895,6 @@ def _sharded_adam_tree(g_leaves, mu_leaves, nu_leaves, spec_leaves, mesh, *,
     leaf plans and bucketing decisions re-derive from local shapes). With
     ``with_health`` each shard's in-pass rows are completed by one stacked
     (n, 2) psum and returned as a replicated :class:`StepHealth`."""
-    from ..sharding.logical import shard_map
     from ..sharding.shardspec import even_spec
     from jax.sharding import PartitionSpec as P
 
@@ -912,9 +911,9 @@ def _sharded_adam_tree(g_leaves, mu_leaves, nu_leaves, spec_leaves, mesh, *,
         return out[:3] + (_psum_health_rows(out[3], repl, axes),)
 
     out_specs = (specs, specs, specs) + ((P(),) if with_health else ())
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P(), specs, specs, specs),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(), specs, specs, specs),
+                       out_specs=out_specs, check_vma=False)
     out = fn(count, list(g_leaves), list(mu_leaves), list(nu_leaves))
     if not with_health:
         return out
@@ -939,7 +938,6 @@ def _sharded_slim_tree(g_leaves, mu_leaves, nu_leaves, dims_leaves, spec_leaves,
     ``with_health`` appends a replicated :class:`StepHealth`: every regime's
     local rows come from its own in-pass accumulator (psum leaves from the
     partial-stats kernel), completed by one stacked (n, 2) psum."""
-    from ..sharding.logical import shard_map
     from jax.sharding import PartitionSpec as P
 
     plans = sharded_tree_plans(g_leaves, dims_leaves, spec_leaves, mesh,
@@ -1056,9 +1054,9 @@ def _sharded_slim_tree(g_leaves, mu_leaves, nu_leaves, dims_leaves, spec_leaves,
 
         out_specs = ((g_specs, g_specs, v_specs)
                      + ((snr_specs,) if emit_snr else ()) + health_spec)
-        fn = shard_map(local_fn, mesh=mesh,
-                       in_specs=(P(), g_specs, g_specs, v_specs),
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(P(), g_specs, g_specs, v_specs),
+                           out_specs=out_specs, check_vma=False)
         res = fn(count, list(g_leaves), list(mu_leaves), list(nu_leaves))
         u, mo, vo, out_s, h = unpack(res)
     else:
@@ -1068,9 +1066,9 @@ def _sharded_slim_tree(g_leaves, mu_leaves, nu_leaves, dims_leaves, spec_leaves,
 
         out_specs = ((g_specs, v_specs)
                      + ((snr_specs,) if emit_snr else ()) + health_spec)
-        fn = shard_map(local_fn_no_mu, mesh=mesh,
-                       in_specs=(P(), g_specs, v_specs),
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(local_fn_no_mu, mesh=mesh,
+                           in_specs=(P(), g_specs, v_specs),
+                           out_specs=out_specs, check_vma=False)
         res = fn(count, list(g_leaves), list(nu_leaves))
         u, _, vo, out_s, h = unpack((res[0], None) + res[1:])
         mo = None
@@ -1153,7 +1151,7 @@ def adam_tree_update(g_leaves: Sequence[jnp.ndarray], mu_leaves: Sequence[jnp.nd
     counts and the finite-masked global grad sumsq, accumulated in the same
     kernel/XLA passes that stream the update (O(leaves) scalar outputs, no
     extra tensor traffic; under a mesh, one stacked (n, 2) psum)."""
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = default_interpret(interpret)
     if _use_sharded(mesh, spec_leaves) and len(g_leaves):
         return _sharded_adam_tree(g_leaves, mu_leaves, nu_leaves, spec_leaves, mesh,
                                   b1=b1, b2=b2, eps=eps, count=count,
@@ -1297,7 +1295,7 @@ def slim_tree_update(g_leaves: Sequence[jnp.ndarray], mu_leaves: Optional[Sequen
     math per leaf (see :func:`set_kernel_fault_hook` /
     :func:`kernel_degraded_leaves`) — a compile regression costs bandwidth,
     not the run."""
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = default_interpret(interpret)
     if _use_sharded(mesh, spec_leaves) and len(g_leaves):
         return _sharded_slim_tree(g_leaves, mu_leaves, nu_leaves, dims_leaves,
                                   spec_leaves, mesh, b1=b1, b2=b2, eps=eps,

@@ -6,12 +6,12 @@ Serving fast path
 The fast path replaces per-request dense ``(B, S_max)`` KV caches with a
 shared **page pool** per attention slot:
 
-    pool:  (n_pages, page_size, 2 * kv_heads, head_dim)   one per layer slot
+    pool:  (n_pages, 2 * kv_heads, page_size, head_dim)   one per layer slot
     table: (max_slots, max_pages) int32                   page ids per row
     page 0 = reserved null page (padding / inactive-row scatter target)
 
-K and V for one position are fused in one page row (K even / V odd head
-indices), so the ragged Pallas decode kernel
+K and V of each kv head sit on adjacent head indices of one page (K even,
+V odd), so the ragged Pallas decode kernel
 (:mod:`repro.kernels.paged_attention`) streams each page with a single
 double-buffered block DMA, walking the row's page table via scalar
 prefetch. Chunked prefill pushes ``prefill_chunk`` prompt tokens through

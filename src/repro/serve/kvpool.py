@@ -1,13 +1,15 @@
 """Host-side paged KV pool: fixed pages, per-request tables, freelist.
 
 Device layout (one pool per attention slot, stacked over periods by
-:func:`repro.models.transformer.init_paged_pools`):
+:func:`repro.models.transformer.init_paged_pools`; owned by
+:mod:`repro.kernels.paged_attention`, see ``pool_shape``):
 
-    (n_pages, page_size, 2 * kv_heads, head_dim)
+    (n_pages, 2 * kv_heads, page_size, head_dim)
 
-K and V for one position live *fused* in one page row — K on even head
-indices, V on odd — so the decode kernel streams a whole page (both halves)
-with a single block DMA per grid step instead of two. Page 0 is the
+K and V live *fused* in one page — K on even head indices, V on odd — so
+the decode kernel streams a whole page (both halves) with a single block
+DMA per grid step instead of two, and each head's K or V is one whole
+``(page_size, head_dim)`` tile. Page 0 is the
 **reserved null page**: padded table entries and inactive-row scatter
 writes are routed there, and it is never read because those rows report
 length 0 (the kernel's ragged mask skips them), so it can hold arbitrary
@@ -130,9 +132,3 @@ class KVPool:
         """Return pages taken by :meth:`reserve` to the freelist."""
         if pages:
             self.release(pages, RESERVED_RID)
-
-
-def pool_shape(n_pages: int, page_size: int, n_kv_heads: int,
-               head_dim: int) -> tuple:
-    """Device array shape of one (unstacked) pool in the fused layout."""
-    return (n_pages, page_size, 2 * n_kv_heads, head_dim)

@@ -24,25 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
-# jax.shard_map graduated from jax.experimental in 0.4.38; import from
-# whichever home this jax has so call sites stay version-agnostic.
-try:
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # jax < 0.4.38
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
-    """Version-agnostic shard_map: the stabilized ``jax.shard_map`` renamed
-    ``check_rep`` to ``check_vma``; translate so call sites (the sharded
-    fused optimizer / SNR paths pass ``check_rep=False``) work on both."""
-    try:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_rep)
-    except TypeError:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=check_rep)
-
 _ctx = threading.local()
 
 

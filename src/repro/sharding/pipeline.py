@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .logical import shard_map
 
 
 def gpipe(stage_fn: Callable, stage_params, x_micro: jnp.ndarray, *, mesh: Mesh,
@@ -68,7 +67,7 @@ def gpipe(stage_fn: Callable, stage_params, x_micro: jnp.ndarray, *, mesh: Mesh,
 
     in_specs = (pspec, P(axis))  # payload replicated via leading fake stage dim
     xs_tiled = jnp.broadcast_to(x_micro[None], (n_stages,) + x_micro.shape)
-    out = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(axis))(
+    out = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(axis))(
         stage_params, xs_tiled)
     return out[0]
 

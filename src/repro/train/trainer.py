@@ -297,6 +297,18 @@ class Trainer:
 
     # -- main loop -----------------------------------------------------------
 
+    def compile_step(self):
+        """Ahead-of-time compile of the train step :meth:`run` takes next,
+        for the shapes of the next batch. The result answers
+        ``memory_analysis()`` (does this batch fit the device?) and
+        ``as_text()`` (which kernels the step runs) before any step runs;
+        :meth:`run` then reuses the compiled program through JAX's caches."""
+        batch = {k: jnp.asarray(v) for k, v in self.data.batch(self.step).items()}
+        args = (self.params, self.opt_state, batch)
+        if self.guard is not None:
+            args += ({"lr_scale": jnp.float32(1.0), "grad_scale": jnp.float32(1.0)},)
+        return self._train_step.lower(*args).compile()
+
     def run(self, steps: Optional[int] = None) -> Dict[str, float]:
         steps = steps if steps is not None else self.tc.total_steps
         t0 = time.time()

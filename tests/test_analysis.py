@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis import kernelcheck, lint, races, registry, shardcheck, tracecheck
 from repro.analysis.jaxpr_tools import aliased_grid_dims
@@ -52,7 +53,7 @@ def _acc_call(body, x, semantics):
         out_specs=pl.BlockSpec((2,), lambda i, j: (0,)),
         out_shape=jax.ShapeDtypeStruct((2,), jnp.float32),
         interpret=True,
-        compiler_params=dict(mosaic=dict(dimension_semantics=semantics)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
     )(x)
 
 

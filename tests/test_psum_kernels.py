@@ -279,7 +279,8 @@ from repro.core.slim_adam import scale_by_slim_adam
 from repro.optim import fused as F
 from repro.sharding.shardspec import owner_factor, regime_counts
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 key = jax.random.PRNGKey(0)
 params = {
     "fanin": jax.random.normal(key, (32, 16)),       # local kernel

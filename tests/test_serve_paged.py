@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_reduced
-from repro.kernels.paged_attention import paged_attention, paged_attention_ref
+from repro.kernels.paged_attention import (paged_attention, paged_attention_ref,
+                                           paged_fits, pool_shape)
 from repro.serve import Engine, Request, ServeConfig
 
 
@@ -22,7 +23,7 @@ def _rand_pool_case(key, *, b, kv, rep, hd, page, max_pages, pool_dtype):
     (one full-page row, one mid-page row, one empty row when b >= 3)."""
     k1, k2 = jax.random.split(key)
     n_pages = b * max_pages + 1
-    pool = jax.random.normal(k1, (n_pages, page, 2 * kv, hd)).astype(pool_dtype)
+    pool = jax.random.normal(k1, pool_shape(n_pages, page, kv, hd)).astype(pool_dtype)
     table = (1 + np.arange(b * max_pages, dtype=np.int32)).reshape(b, max_pages)
     lengths = np.zeros((b,), np.int32)
     lengths[0] = max_pages * page            # every page full
@@ -78,6 +79,21 @@ class TestPagedKernel:
             np.testing.assert_allclose(np.asarray(chunk[:, i]),
                                        np.asarray(tok[:, 0]),
                                        atol=3e-5, rtol=3e-5)
+
+    def test_fit_gate_charges_padded_stats(self):
+        """The softmax stats (KV, C * rep, 1) take a full 128-lane tile each
+        on the TPU. With 64 query heads over 8 kv heads at head_dim 128 they
+        are as large as the q block, so a 128-token chunk no longer fits
+        VMEM_BUDGET while a 32-token one does."""
+        h, kv, hd, page = 64, 8, 128, 16
+        assert paged_fits(32, h, hd, page, 2 * kv)
+        assert not paged_fits(128, h, hd, page, 2 * kv)
+        q = jnp.zeros((1, 128, h, hd), jnp.float32)
+        pool = jnp.zeros(pool_shape(3, page, kv, hd), jnp.float32)
+        table = jnp.asarray([[1, 2]], jnp.int32)
+        lengths = jnp.asarray([2 * page], jnp.int32)
+        jaxpr = jax.make_jaxpr(paged_attention)(q, pool, table, lengths)
+        assert "pallas_call" not in str(jaxpr)    # rejected -> reference
 
 
 def _mk(arch="gpt_small", **sc_kw):

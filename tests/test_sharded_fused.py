@@ -164,7 +164,8 @@ from repro.optim.adam import scale_by_adam
 from repro.optim import fused as F
 from repro.sharding.shardspec import regime_counts
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 key = jax.random.PRNGKey(0)
 params = {
     "fanin": jax.random.normal(key, (32, 16)),        # K=(1,), kept dim sharded -> local kernel

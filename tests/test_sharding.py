@@ -90,7 +90,8 @@ from repro.sharding.logical import ShardingContext, param_specs, use_sharding
 from repro.sharding.state_shardings import opt_state_specs
 from repro.train.step import make_train_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_reduced("smollm_135m")
 ctx = ShardingContext(mesh)
 with use_sharding(ctx):
@@ -151,7 +152,8 @@ import json
 import jax, jax.numpy as jnp
 from repro.sharding.pipeline import gpipe, sequential_reference
 
-mesh = jax.make_mesh((4,), ("pipe",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("pipe",))
 P_stages, M, mb, d = 4, 8, 2, 16
 key = jax.random.PRNGKey(0)
 stage_params = {"w": jax.random.normal(key, (P_stages, d, d)) * 0.3,
