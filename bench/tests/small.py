@@ -1,0 +1,47 @@
+"""Small cells for CPU tests: each configuration at its program's
+``reduced()`` size (float32 compute) and traffic shrunk to fit a test."""
+import json
+
+from repro.configs import get_reduced
+
+from bench import spec
+
+
+def config(name: str):
+    """(configuration dict, the program's ModelConfig) at the reduced size."""
+    cfg = json.loads((spec.BENCH / "configs" / f"{name}.json").read_text())
+    m = get_reduced(name)
+    cfg.update(num_hidden_layers=m.n_layers, hidden_size=m.d_model,
+               num_attention_heads=m.n_heads, num_key_value_heads=m.n_kv_heads,
+               head_dim=m.hd, intermediate_size=m.d_ff, vocab_size=m.vocab_size,
+               compute_dtype="float32")
+    if cfg["position"] == "learned":
+        cfg["max_position_embeddings"] = m.max_position
+    # At this width, weights of std 0.02 leave every position's top logit on
+    # its own token whatever the context; at 0.3 the context decides it (as
+    # it does at the published width with 0.02), so a broken cache shows.
+    cfg["init"] = dict(cfg["init"], std=0.3)
+    return cfg, m
+
+
+def cell(workload: str):
+    c = spec.load_cell(workload)
+    c.config, model = config(c.config["name"])
+    t = c.traffic
+    if t["kind"] == "train":
+        t.update(batch=2, seq=32, stride_steps=2, check_rows=1)
+    else:
+        t["serve"] = {"page_size": 8, "prefill_chunk": 16, "max_seq": 128,
+                      "max_slots": 4}
+        t["check_requests"] = 3
+        if t["kind"] == "serve_open":
+            t.update(rate_rps=4.0, warmup_s=0.5, drain_limit_s=30,
+                     prompt={"dist": "lognormal", "median": 20, "sigma": 1.0,
+                             "min": 4, "max": 60},
+                     output={"dist": "lognormal", "median": 6, "sigma": 0.8,
+                             "min": 2, "max": 12})
+        else:
+            t.update(backlog=4,
+                     prompt={"dist": "uniform", "min": 40, "max": 90},
+                     output={"dist": "uniform", "min": 4, "max": 12})
+    return c, model
