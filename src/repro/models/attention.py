@@ -421,8 +421,9 @@ def attention_paged_decode(p, x: jnp.ndarray, pool: jnp.ndarray,
         rope_sincos = rotary_embedding(pos[:, None], cfg.head_dim, cfg.rope_base)
     q, k_new, v_new = _project_qkv(p, x, rope_sincos, None)
 
-    pool = pool_write(pool, table, jnp.arange(b), pos, active,
-                      k_new[:, 0], v_new[:, 0])
+    with jax.named_scope("kv_pool"):
+        pool = pool_write(pool, table, jnp.arange(b), pos, active,
+                          k_new[:, 0], v_new[:, 0])
 
     kv_len = jnp.where(active, pos + 1, 0).astype(jnp.int32)
     out = paged_attention(q, pool, table, kv_len, interpret=interpret,
@@ -453,8 +454,9 @@ def attention_paged_prefill(p, x: jnp.ndarray, pool: jnp.ndarray,
     q, k_new, v_new = _project_qkv(p, x, rope_sincos, None)
 
     valid = jnp.arange(c) < n_valid
-    pool = pool_write(pool, table_row, jnp.zeros(c, jnp.int32), positions,
-                      valid, k_new[0], v_new[0])
+    with jax.named_scope("kv_pool"):
+        pool = pool_write(pool, table_row, jnp.zeros(c, jnp.int32), positions,
+                          valid, k_new[0], v_new[0])
 
     kv_len = jnp.asarray(pos0 + c, jnp.int32)[None]
     out = paged_attention(q, pool, table_row, kv_len, interpret=interpret,

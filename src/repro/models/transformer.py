@@ -469,14 +469,18 @@ def paged_decode_step(cfg: ModelConfig, params, state: PagedState,
             key = f"slot_{i}"
             p = period_params[key]
             if slot.mixer == "attn":
-                pool = jax.lax.dynamic_index_in_dim(pools[key], idx, 0, keepdims=False)
+                with jax.named_scope("kv_pool"):
+                    pool = jax.lax.dynamic_index_in_dim(pools[key], idx, 0,
+                                                        keepdims=False)
                 y, pool = attention_paged_decode(
                     p["attn"], _norm(cfg, p["mixer_norm"], x), pool,
                     state.table, state.lengths, state.active, cfg.attn_cfg(),
                     use_ref=attn_impl == "ref")
                 x = x + y
                 pools = dict(pools)
-                pools[key] = jax.lax.dynamic_update_index_in_dim(pools[key], pool, idx, 0)
+                with jax.named_scope("kv_pool"):
+                    pools[key] = jax.lax.dynamic_update_index_in_dim(
+                        pools[key], pool, idx, 0)
             if slot.ffn == "dense":
                 x = x + mlp_forward(p["mlp"], _norm(cfg, p["ffn_norm"], x), gated=cfg.gated_mlp)
             elif slot.ffn == "moe":
@@ -527,14 +531,18 @@ def paged_prefill_chunk(cfg: ModelConfig, params, pools: Dict[str, jnp.ndarray],
             key = f"slot_{i}"
             p = period_params[key]
             if slot.mixer == "attn":
-                pool = jax.lax.dynamic_index_in_dim(pools[key], idx, 0, keepdims=False)
+                with jax.named_scope("kv_pool"):
+                    pool = jax.lax.dynamic_index_in_dim(pools[key], idx, 0,
+                                                        keepdims=False)
                 y, pool = attention_paged_prefill(
                     p["attn"], _norm(cfg, p["mixer_norm"], x), pool,
                     table_row, pos0, n_valid, cfg.attn_cfg(),
                     use_ref=attn_impl == "ref")
                 x = x + y
                 pools = dict(pools)
-                pools[key] = jax.lax.dynamic_update_index_in_dim(pools[key], pool, idx, 0)
+                with jax.named_scope("kv_pool"):
+                    pools[key] = jax.lax.dynamic_update_index_in_dim(
+                        pools[key], pool, idx, 0)
             if slot.ffn == "dense":
                 x = x + mlp_forward(p["mlp"], _norm(cfg, p["ffn_norm"], x), gated=cfg.gated_mlp)
             elif slot.ffn == "moe":

@@ -87,7 +87,8 @@ retry. ``ValueError`` remains reserved for requests that could never run.
 **Metrics.** ``Engine.metrics()`` snapshots a frozen
 :class:`ServeMetrics`: gauges (queue depth, active slots, free/used pages,
 high-water), scheduler counters (admitted/retired/preempted, step/chunk/
-token counts), every fault counter above, and TTFT/TPOT aggregates.
+token counts, decode rows live and computed), and every fault counter
+above. Latency is per request: ``Completion.ttft_s``/``tpot_s``/``queue_s``.
 Hot-loop conditions warn only on first occurrence (see
 ``ServeCounters.warn_once``); recurrence is what the counters are for.
 

@@ -112,7 +112,9 @@ class Request:
 class Completion:
     """Result of one request. ``tokens`` holds only the *generated* suffix
     (including the eos token when one was emitted). ``finish_reason``:
-    'eos' | 'length' | 'budget' | 'deadline' | 'nan'."""
+    'eos' | 'length' | 'budget' | 'deadline' | 'nan'. ``queue_s`` is the
+    time spent queued for a slot, from submission to admission, counting
+    again after every preemption."""
     id: int
     prompt: np.ndarray
     tokens: np.ndarray
@@ -121,6 +123,7 @@ class Completion:
     wall_s: float                        # submit -> retirement
     preemptions: int = 0
     tpot_s: Optional[float] = None       # mean time per token after the first
+    queue_s: float = 0.0                 # queued for a slot (all spells)
 
 
 class _ReqState:
@@ -128,7 +131,7 @@ class _ReqState:
 
     __slots__ = ("rid", "request", "prompt", "max_new", "generated",
                  "ctx_len", "t_submit", "t_first", "preemptions",
-                 "deadline_s", "priority")
+                 "deadline_s", "priority", "t_queued", "queue_s")
 
     def __init__(self, rid: int, request: Request, prompt: np.ndarray,
                  max_new: int, t_submit: float):
@@ -143,6 +146,12 @@ class _ReqState:
         self.preemptions = 0
         self.deadline_s = request.deadline_s
         self.priority = request.priority
+        self.t_queued: Optional[float] = t_submit   # None while in a slot
+        self.queue_s = 0.0
+
+    def leave_queue(self, now: float) -> None:
+        self.queue_s += now - self.t_queued
+        self.t_queued = None
 
     def ctx_tokens(self) -> np.ndarray:
         """Tokens whose KV must exist before decoding can continue — the
@@ -164,6 +173,8 @@ class Engine:
         self._done: Dict[int, Completion] = {}
         self.counters = ServeCounters()
         self.decode_steps = 0
+        self.decode_rows = 0            # active rows, summed over decode calls
+        self.decode_rows_computed = 0   # rows each decode call ran (max_slots)
         self.prefill_chunks = 0
         self.tokens_out = 0
         self.sched_steps = 0        # scheduler iterations, incl. no-progress
@@ -182,23 +193,33 @@ class Engine:
             self.pool = KVPool(n_pages, p)
             self.scheduler = Scheduler(self.sc.max_slots, max_pages, self.pool)
             self._pools = None     # device pools, created on first use
-            self._decode = jax.jit(
-                lambda pr, st, t: transformer.paged_decode_step(model_cfg, pr, st, t))
-            self._decode_fallback = jax.jit(
-                lambda pr, st, t: transformer.paged_decode_step(
-                    model_cfg, pr, st, t, attn_impl="ref"))
-            self._prefill = jax.jit(
-                lambda pr, pools, row, pos0, nv, tok:
-                transformer.paged_prefill_chunk(model_cfg, pr, pools, row,
-                                                pos0, nv, tok))
-            self._prefill_fallback = jax.jit(
-                lambda pr, pools, row, pos0, nv, tok:
-                transformer.paged_prefill_chunk(model_cfg, pr, pools, row,
-                                                pos0, nv, tok,
-                                                attn_impl="ref"))
+            # Named functions, so each program is an XLA module of its own
+            # name in a profile (``jit_paged_decode``, ...).
+            def paged_decode(pr, st, t):
+                return transformer.paged_decode_step(model_cfg, pr, st, t)
+
+            def paged_decode_ref(pr, st, t):
+                return transformer.paged_decode_step(model_cfg, pr, st, t,
+                                                     attn_impl="ref")
+
+            def paged_prefill(pr, pools, row, pos0, nv, tok):
+                return transformer.paged_prefill_chunk(model_cfg, pr, pools,
+                                                       row, pos0, nv, tok)
+
+            def paged_prefill_ref(pr, pools, row, pos0, nv, tok):
+                return transformer.paged_prefill_chunk(model_cfg, pr, pools,
+                                                       row, pos0, nv, tok,
+                                                       attn_impl="ref")
+
+            self._decode = jax.jit(paged_decode)
+            self._decode_fallback = jax.jit(paged_decode_ref)
+            self._prefill = jax.jit(paged_prefill)
+            self._prefill_fallback = jax.jit(paged_prefill_ref)
         else:
-            self._decode = jax.jit(
-                lambda pr, c, t: transformer.decode_step(model_cfg, pr, c, t))
+            def dense_decode(pr, c, t):
+                return transformer.decode_step(model_cfg, pr, c, t)
+
+            self._decode = jax.jit(dense_decode)
 
     def _now(self) -> float:
         return time.monotonic() + self._clock_skew
@@ -273,39 +294,60 @@ class Engine:
                                 + self._reqs[rid].max_new)
             for rid in self.scheduler.queue)
 
-    def step(self) -> Dict[str, float]:
+    def step(self) -> None:
         """One scheduler iteration: expire deadlines, admit + prefill,
-        grow/preempt, one batched decode, retire. Returns per-step
-        metrics."""
+        grow/preempt, one batched decode, retire. In a profile the step and
+        its phases are ``repro.serve.*`` spans: ``step`` around all of it,
+        ``admit`` (``prefill`` inside it, one per admitted request),
+        ``decode``, ``readback`` and ``sample``."""
         if not self._paged:
             raise NotImplementedError(
                 f"the request API needs the paged fast path, which does not "
                 f"cover arch '{self.cfg.name}' — use generate()")
-        sched = self.scheduler
-        step_idx = self.sched_steps
-        self.sched_steps += 1
-        injection.fire(STEP_POINT, self, step_idx)
-        skew = injection.fire(CLOCK_POINT, step_idx)
-        if skew:
-            self._clock_skew += float(skew)
-            self.counters.injected_stalls += 1
-        self._expire_deadlines()
+        with jax.profiler.TraceAnnotation("repro.serve.step"):
+            step_idx = self.sched_steps
+            self.sched_steps += 1
+            injection.fire(STEP_POINT, self, step_idx)
+            skew = injection.fire(CLOCK_POINT, step_idx)
+            if skew:
+                self._clock_skew += float(skew)
+                self.counters.injected_stalls += 1
+            self._expire_deadlines()
+            if self._admit_freeze > 0:
+                self._admit_freeze -= 1      # backoff: no admissions this step
+            else:
+                with jax.profiler.TraceAnnotation("repro.serve.admit"):
+                    self._admit_queued()
+            self._grow_or_preempt()
+            active = self.scheduler.active_slots()
+            if not active:
+                return
+            with jax.profiler.TraceAnnotation("repro.serve.decode"):
+                logits, ok_dev = self._decode_active(active)
+            with jax.profiler.TraceAnnotation("repro.serve.readback"):
+                last = np.asarray(logits[:, -1].astype(jnp.float32))
+                ok = np.asarray(ok_dev)
+            with jax.profiler.TraceAnnotation("repro.serve.sample"):
+                self._sample_active(active, last, ok)
 
-        prefills = 0
-        if self._admit_freeze > 0:
-            self._admit_freeze -= 1      # backoff: no admissions this step
-        else:
-            # --- admit as many queue heads as slots/pages allow
-            while sched.queue:
-                rid = sched.queue[0]
-                st = self._reqs[rid]
-                slot = sched.try_admit(rid, len(st.ctx_tokens()))
-                if slot is None:
-                    break
-                prefills += 1
+    def _admit_queued(self) -> None:
+        """Admit as many queue heads as slots and pages allow, prefilling
+        each."""
+        sched = self.scheduler
+        while sched.queue:
+            rid = sched.queue[0]
+            st = self._reqs[rid]
+            slot = sched.try_admit(rid, len(st.ctx_tokens()))
+            if slot is None:
+                break
+            st.leave_queue(self._now())
+            with jax.profiler.TraceAnnotation("repro.serve.prefill", rid=rid):
                 self._prefill_into(slot, st)
 
-        # --- make room for every active row's next write position
+    def _grow_or_preempt(self) -> None:
+        """Make room for every active row's next write position, preempting
+        the youngest other row when the pool is exhausted."""
+        sched = self.scheduler
         ensured: List[int] = []
         for slot, rid in list(sched.active_slots()):
             if sched.slot_rid[slot] != rid:
@@ -318,66 +360,66 @@ class Engine:
                 victim = sched.youngest_other(slot, tuple(ensured))
                 vrid = sched.preempt(victim if victim is not None else slot)
                 self._reqs[vrid].preemptions += 1
+                self._reqs[vrid].t_queued = self._now()
                 if victim is None:
                     break              # self-preempted; skip decode this step
 
-        # --- one fixed-shape decode over all active slots
-        step_tokens = 0
-        active = sched.active_slots()
-        if active:
-            n = self.sc.max_slots
-            tokens = np.zeros((n, 1), np.int32)
-            lengths = np.zeros((n,), np.int32)
-            mask = np.zeros((n,), bool)
-            for slot, rid in active:
-                st = self._reqs[rid]
-                tokens[slot, 0] = st.generated[-1]
-                lengths[slot] = st.ctx_len
-                mask[slot] = True
-            state = transformer.PagedState(
-                pools=self._device_pools(), table=jnp.asarray(sched.table),
-                lengths=jnp.asarray(lengths), active=jnp.asarray(mask))
-            logits, ok_dev, new_state = self._decode_call(
-                state, jnp.asarray(tokens))
-            self._pools = new_state.pools
-            self.decode_steps += 1
-            last = np.asarray(logits[:, -1].astype(jnp.float32))
-            ok = np.asarray(ok_dev)
-            now = self._now()
-            for slot, rid in active:
-                st = self._reqs[rid]
-                st.ctx_len += 1        # this step wrote generated[-1]'s KV
-                poisoned = bool(injection.fire(
-                    LOGITS_POINT, rid, len(st.generated)))
-                if poisoned:
-                    self.counters.injected_poison += 1
-                if poisoned or not ok[slot]:
-                    self._retire_nan(slot, st)
-                    continue
-                tok = self._sample_one(st, last[slot])
-                st.generated.append(tok)
-                step_tokens += 1
-                eos = st.request.eos_id
-                if eos is not None and tok == eos:
-                    self._retire(slot, st, "eos")
-                elif len(st.generated) >= st.max_new:
-                    self._retire(slot, st, "length")
-                elif (self.sc.max_wall_s is not None
-                      and now - st.t_submit > self.sc.max_wall_s):
-                    self.counters.budget_truncated += 1
-                    self.counters.warn_once(
-                        "wall_budget",
-                        f"serve request {rid} exceeded wall-clock budget "
-                        f"max_wall_s={self.sc.max_wall_s} after "
-                        f"{len(st.generated)}/{st.max_new} tokens; returning "
-                        f"truncated response (counted in "
-                        f"ServeMetrics.budget_truncated; warning not "
-                        f"repeated)")
-                    self._retire(slot, st, "budget")
-        self.tokens_out += step_tokens
-        m = sched.metrics()
-        m.update(step_tokens=float(step_tokens), prefills=float(prefills))
-        return m
+    def _decode_active(self, active):
+        """Dispatch one fixed-shape decode over all ``max_slots`` rows, the
+        active ones live. Returns the (logits, ok) device arrays."""
+        n = self.sc.max_slots
+        tokens = np.zeros((n, 1), np.int32)
+        lengths = np.zeros((n,), np.int32)
+        mask = np.zeros((n,), bool)
+        for slot, rid in active:
+            st = self._reqs[rid]
+            tokens[slot, 0] = st.generated[-1]
+            lengths[slot] = st.ctx_len
+            mask[slot] = True
+        state = transformer.PagedState(
+            pools=self._device_pools(), table=jnp.asarray(self.scheduler.table),
+            lengths=jnp.asarray(lengths), active=jnp.asarray(mask))
+        logits, ok_dev, new_state = self._decode_call(state, jnp.asarray(tokens))
+        self._pools = new_state.pools
+        self.decode_steps += 1
+        self.decode_rows += len(active)
+        self.decode_rows_computed += n
+        return logits, ok_dev
+
+    def _sample_active(self, active, last: np.ndarray, ok: np.ndarray) -> None:
+        """Sample each active row's next token from its logits row and retire
+        the rows that finished (or whose logits were not finite)."""
+        now = self._now()
+        for slot, rid in active:
+            st = self._reqs[rid]
+            st.ctx_len += 1        # this step wrote generated[-1]'s KV
+            poisoned = bool(injection.fire(
+                LOGITS_POINT, rid, len(st.generated)))
+            if poisoned:
+                self.counters.injected_poison += 1
+            if poisoned or not ok[slot]:
+                self._retire_nan(slot, st)
+                continue
+            tok = self._sample_one(st, last[slot])
+            st.generated.append(tok)
+            self.tokens_out += 1
+            eos = st.request.eos_id
+            if eos is not None and tok == eos:
+                self._retire(slot, st, "eos")
+            elif len(st.generated) >= st.max_new:
+                self._retire(slot, st, "length")
+            elif (self.sc.max_wall_s is not None
+                  and now - st.t_submit > self.sc.max_wall_s):
+                self.counters.budget_truncated += 1
+                self.counters.warn_once(
+                    "wall_budget",
+                    f"serve request {rid} exceeded wall-clock budget "
+                    f"max_wall_s={self.sc.max_wall_s} after "
+                    f"{len(st.generated)}/{st.max_new} tokens; returning "
+                    f"truncated response (counted in "
+                    f"ServeMetrics.budget_truncated; warning not "
+                    f"repeated)")
+                self._retire(slot, st, "budget")
 
     def run_until_drained(self) -> Dict[int, Completion]:
         """Step until every admitted request has retired; returns and
@@ -427,6 +469,8 @@ class Engine:
                           admitted=0, retired=0, preempted=0)
         return ServeMetrics(
             sched_steps=self.sched_steps, decode_steps=self.decode_steps,
+            decode_rows=self.decode_rows,
+            decode_rows_computed=self.decode_rows_computed,
             prefill_chunks=self.prefill_chunks, tokens_out=self.tokens_out,
             degraded_steps=c.degraded_steps, nan_retired=c.nan_retired,
             deadline_expired=c.deadline_expired,
@@ -435,10 +479,7 @@ class Engine:
             rejected_queue=c.rejected_queue, rejected_pool=c.rejected_pool,
             livelock_backoffs=c.livelock_backoffs,
             injected_stalls=c.injected_stalls,
-            injected_poison=c.injected_poison,
-            ttft_mean_s=c.ttft_sum_s / c.ttft_n if c.ttft_n else None,
-            tpot_mean_s=c.tpot_sum_s / c.tpot_n if c.tpot_n else None,
-            **gauges)
+            injected_poison=c.injected_poison, **gauges)
 
     # ------------------------------------------------------------------
     # Progress / livelock handling
@@ -622,25 +663,21 @@ class Engine:
         self._finish(st, reason)
 
     def _finish(self, st: _ReqState, reason: str) -> None:
-        """Build the Completion and fold its latency stats into the
-        engine-level TTFT/TPOT aggregates."""
+        """Build the Completion (a request dropped from the queue ends its
+        queued spell here)."""
         now = self._now()
+        if st.t_queued is not None:
+            st.leave_queue(now)
         ttft = None if st.t_first is None else st.t_first - st.t_submit
         wall = now - st.t_submit
         tpot = None
         if ttft is not None and len(st.generated) > 1:
             tpot = (wall - ttft) / (len(st.generated) - 1)
-        if ttft is not None:
-            self.counters.ttft_sum_s += ttft
-            self.counters.ttft_n += 1
-        if tpot is not None:
-            self.counters.tpot_sum_s += tpot
-            self.counters.tpot_n += 1
         self._done[st.rid] = Completion(
             id=st.rid, prompt=st.prompt,
             tokens=np.asarray(st.generated, np.int32),
             finish_reason=reason, ttft_s=ttft, wall_s=wall,
-            preemptions=st.preemptions, tpot_s=tpot)
+            preemptions=st.preemptions, tpot_s=tpot, queue_s=st.queue_s)
         del self._reqs[st.rid]
         self._completed_total += 1
 

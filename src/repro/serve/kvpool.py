@@ -72,9 +72,6 @@ class KVPool:
     def used_pages(self) -> int:
         return len(self._owner)
 
-    def utilization(self) -> float:
-        return self.used_pages / self.capacity
-
     def pages_for(self, n_tokens: int) -> int:
         """Pages needed to store ``n_tokens`` positions (0 tokens -> 0)."""
         return -(-n_tokens // self.page_size)

@@ -13,7 +13,7 @@ view.
   :meth:`ServeCounters.warn_once` for first-occurrence-only warnings
   (the counter keeps counting after the warning stops).
 * :class:`ServeMetrics` — immutable snapshot: counters + scheduler/pool
-  gauges + TTFT/TPOT aggregates. ``to_dict()`` feeds the bench history.
+  gauges. ``to_dict()`` feeds the bench history.
 * :class:`Rejected` — ``Engine.submit`` admission-control verdict
   (backpressure instead of unbounded queueing).
 * :class:`LivelockError` — raised only after deterministic backoff fails;
@@ -33,8 +33,7 @@ class ServeCounters:
     __slots__ = ("degraded_steps", "nan_retired", "deadline_expired",
                  "budget_truncated", "truncated_max_new", "rejected_queue",
                  "rejected_pool", "livelock_backoffs", "injected_stalls",
-                 "injected_poison", "ttft_sum_s", "ttft_n", "tpot_sum_s",
-                 "tpot_n", "_warned")
+                 "injected_poison", "_warned")
 
     def __init__(self) -> None:
         self.degraded_steps = 0       # kernel launches degraded to the ref path
@@ -47,10 +46,6 @@ class ServeCounters:
         self.livelock_backoffs = 0    # no-progress backoff rounds
         self.injected_stalls = 0      # fault-plan clock skews applied
         self.injected_poison = 0      # fault-plan logit poisonings applied
-        self.ttft_sum_s = 0.0         # time-to-first-token aggregate
-        self.ttft_n = 0
-        self.tpot_sum_s = 0.0         # time-per-output-token aggregate
-        self.tpot_n = 0
         self._warned: Set[str] = set()
 
     def warn_once(self, code: str, message: str) -> None:
@@ -86,6 +81,8 @@ class ServeMetrics:
     preempted: int
     sched_steps: int
     decode_steps: int
+    decode_rows: int              # active rows, summed over decode calls
+    decode_rows_computed: int     # rows the decode calls ran (max_slots each)
     prefill_chunks: int
     tokens_out: int
     # fault / SLO counters (mirrors ServeCounters)
@@ -99,9 +96,6 @@ class ServeMetrics:
     livelock_backoffs: int
     injected_stalls: int
     injected_poison: int
-    # latency aggregates (None until a request has retired with the stat)
-    ttft_mean_s: Optional[float]
-    tpot_mean_s: Optional[float]
 
     @property
     def preemption_rate(self) -> float:

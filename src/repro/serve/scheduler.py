@@ -187,17 +187,3 @@ class Scheduler:
         self._admit_seq.pop(rid, None)
         self.table[slot, :] = 0
         self.slot_rid[slot] = None
-
-    # ------------------------------------------------------------------
-
-    def metrics(self) -> Dict[str, float]:
-        return {
-            "active": float(len(self.active_slots())),
-            "queued": float(len(self.queue)),
-            "page_utilization": self.pool.utilization(),
-            "free_pages": float(self.pool.free_pages),
-            "admitted": float(self.admitted),
-            "retired": float(self.retired),
-            "preempted": float(self.preempted),
-            "page_high_water": float(self.pool.high_water),
-        }

@@ -90,8 +90,9 @@ def make_train_step(cfg, tx: GradientTransformation, *, forward_fn=None,
 
     def train_step(params, opt_state, batch):
         grads, metrics = compute_grads(params, batch)
-        updates, new_opt_state = tx.update(grads, opt_state, params)
-        new_params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = tx.update(grads, opt_state, params)
+            new_params = apply_updates(params, updates)
         metrics = dict(metrics)
         metrics["grad_norm"] = global_norm(grads)
         return new_params, new_opt_state, metrics
@@ -103,10 +104,12 @@ def make_train_step(cfg, tx: GradientTransformation, *, forward_fn=None,
         grads, metrics = compute_grads(params, batch)
         g_scale = jnp.asarray(controls["grad_scale"], jnp.float32)
         grads = jax.tree.map(lambda g: g * g_scale.astype(g.dtype), grads)
-        updates, new_opt_state = tx.update(grads, opt_state, params)
-        lr_scale = jnp.asarray(controls["lr_scale"], jnp.float32)
-        updates = jax.tree.map(lambda u: u * lr_scale.astype(u.dtype), updates)
-        new_params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = tx.update(grads, opt_state, params)
+            lr_scale = jnp.asarray(controls["lr_scale"], jnp.float32)
+            updates = jax.tree.map(lambda u: u * lr_scale.astype(u.dtype),
+                                   updates)
+            new_params = apply_updates(params, updates)
 
         gn = global_norm(grads)
         health = find_step_health(new_opt_state)

@@ -282,14 +282,15 @@ class Trainer:
         nu = find_adam_nu(self.opt_state)
         if nu is None:
             return
-        from_upd = (find_slim_snr(self.opt_state)
-                    if self._train_step_snr is not None else None)
-        snapshot = measure_tree_snr(
-            nu, self.meta, backend=self.backend,
-            mesh=self.mesh, param_specs=self.param_specs,
-            from_update=from_upd,
-            update_dims=self._update_dims if from_upd is not None else None)
-        self.snr.update(snapshot, self.step)
+        with jax.profiler.TraceAnnotation("repro.train.snr"):
+            from_upd = (find_slim_snr(self.opt_state)
+                        if self._train_step_snr is not None else None)
+            snapshot = measure_tree_snr(
+                nu, self.meta, backend=self.backend,
+                mesh=self.mesh, param_specs=self.param_specs,
+                from_update=from_upd,
+                update_dims=self._update_dims if from_upd is not None else None)
+            self.snr.update(snapshot, self.step)
         if from_upd is not None:
             # Strip the consumed snapshot so checkpoints and the normal
             # step's jit signature keep the snr-less state layout.
@@ -310,6 +311,11 @@ class Trainer:
         return self._train_step.lower(*args).compile()
 
     def run(self, steps: Optional[int] = None) -> Dict[str, float]:
+        """Train up to step ``steps``; returns the last logged metrics. In a
+        profile each step's phases are ``repro.train.*`` spans: ``batch``
+        (the host batch and its copy to the device), ``step`` (the
+        dispatch), ``readback`` (metrics read on the host), ``snr`` and
+        ``checkpoint`` where they run."""
         steps = steps if steps is not None else self.tc.total_steps
         t0 = time.time()
         if self.step >= steps:
@@ -326,8 +332,9 @@ class Trainer:
             return last
         last = {}
         while self.step < steps:
-            batch = self.data.batch(self.step)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            with jax.profiler.TraceAnnotation("repro.train.batch"):
+                batch = self.data.batch(self.step)
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
             # On SNR-cadence steps, run the emit_snr step variant so the
             # measurement rides the update pass (state.snr) instead of
             # paying a separate nu read in maybe_measure_snr.
@@ -342,16 +349,18 @@ class Trainer:
                            if self.faults is not None else 1.0)
                 controls = {"lr_scale": jnp.asarray(self.guard.lr_scale, jnp.float32),
                             "grad_scale": jnp.asarray(g_scale, jnp.float32)}
-                self.params, self.opt_state, metrics = step_fn(
-                    self.params, self.opt_state, batch, controls)
+                with jax.profiler.TraceAnnotation("repro.train.step"):
+                    self.params, self.opt_state, metrics = step_fn(
+                        self.params, self.opt_state, batch, controls)
                 self.step += 1
-                loss = float(metrics["loss"])
+                with jax.profiler.TraceAnnotation("repro.train.readback"):
+                    loss = float(metrics["loss"])
+                    skipped = bool(metrics["step_skipped"] > 0)
+                    nonfinite = float(metrics["nonfinite_count"])
                 if self.faults is not None:
                     loss = self.faults.corrupt_loss(self.step - 1, loss)
-                skipped = bool(metrics["step_skipped"] > 0)
-                action = self.guard.observe(
-                    loss, skipped=skipped,
-                    nonfinite=float(metrics["nonfinite_count"]))
+                action = self.guard.observe(loss, skipped=skipped,
+                                            nonfinite=nonfinite)
                 if skipped:
                     # A measure step that got skipped published SNR from the
                     # discarded update — drop it without consuming.
@@ -362,19 +371,22 @@ class Trainer:
                     self._rollback()
                     continue
             else:
-                self.params, self.opt_state, metrics = step_fn(
-                    self.params, self.opt_state, batch)
+                with jax.profiler.TraceAnnotation("repro.train.step"):
+                    self.params, self.opt_state, metrics = step_fn(
+                        self.params, self.opt_state, batch)
                 self.step += 1
                 self.maybe_measure_snr()
             if self.step % self.tc.log_every == 0 or self.step == steps:
-                last = {k: float(v) for k, v in metrics.items()}
+                with jax.profiler.TraceAnnotation("repro.train.readback"):
+                    last = {k: float(v) for k, v in metrics.items()}
                 last.update(step=self.step, wall_s=round(time.time() - t0, 2))
                 if self.guard is not None:
                     last.update(self.guard.stats(),
                                 ckpt_failures=float(self.ckpt_failures))
                 self.metrics_log.append(last)
             if self.tc.ckpt_every and self.step % self.tc.ckpt_every == 0:
-                self.checkpoint()
+                with jax.profiler.TraceAnnotation("repro.train.checkpoint"):
+                    self.checkpoint()
         return last
 
     def derive_slim_rules(self, cutoff: float = 1.0):
