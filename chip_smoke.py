@@ -194,7 +194,8 @@ def _paged_parity(pool, cfg, lens, seed: int, tag: str) -> None:
 
     from repro.kernels.paged_attention import paged_attention, paged_attention_ref
 
-    n_pages, _, page, hd = pool.shape
+    n_pages, _, page, _ = pool.shape
+    hd = cfg.hd
     slots = len(lens)
     max_pages = (n_pages - 1) // slots
     table = jnp.asarray(1 + np.arange(slots * max_pages, dtype=np.int32)
@@ -234,7 +235,7 @@ def serve_phase(seed: int) -> None:
                      max_slots=8, max_new_tokens=SERVE_NEW_TOKENS)
     for chunk in (1, sc.prefill_chunk):
         check(paged_fits(chunk, cfg.n_heads, cfg.hd, sc.page_size,
-                         2 * cfg.n_kv_heads),
+                         cfg.n_kv_heads),
               f"{tag}: paged_attention would take the reference at C={chunk}")
     params, _ = cfg.init(jax.random.PRNGKey(seed))
     eng = Engine(cfg, params, sc)
@@ -279,7 +280,7 @@ def serve_phase(seed: int) -> None:
     say(f"{tag}: smoke timing, not a benchmark: TTFT mean {ttft * 1e3:.1f} ms, "
         f"TPOT mean {tpot * 1e3:.2f} ms, drain wall {wall:.2f} s")
 
-    pool = eng._device_pools()["slot_0"][0]        # layer 0 of the pool
+    pool = eng._device_pools()["slot_0"][:eng.pool.n_pages]   # layer 0
     _paged_parity(pool, cfg, [int(n) + SERVE_NEW_TOKENS for n in lens], seed,
                   tag)
 

@@ -1,16 +1,20 @@
 """Ragged paged-attention Pallas kernel (decode + chunked prefill).
 
 The serving fast path stores each attention layer's KV cache as a pool of
-fixed-size *pages* in the fused layout ``(n_pages, 2 * kv_heads, page_size,
-head_dim)`` — K and V of each kv head on adjacent (even/odd) head indices,
-so one page DMA streams both, and every head's K or V is a whole
-``(page_size, head_dim)`` tile. This module owns the layout:
-:func:`pool_shape` sizes a pool, :func:`pool_write` stores K/V into it, and
-the kernel and :func:`paged_attention_ref` read it; the page accounting is
+fixed-size *pages* in the lane-dense layout ``(n_pages, kv_heads,
+page_size, 2 * head_dim)``: row ``(p, j, s)`` holds K of kv head ``j`` at
+slot ``s`` of page ``p`` on lanes ``[0, hd)`` and its V on ``[hd, 2hd)``.
+One page DMA streams K and V of every head, and the minor dim (128 at
+head_dim 64, 256 at 128) fills whole 128-lane vregs, so XLA keeps the pool
+row-major with no lane padding and every program reads and writes it in
+place. This module owns the layout: :func:`pool_shape` sizes a pool,
+:func:`pool_write` stores K/V into it, and the kernel and
+:func:`paged_attention_ref` read it; the page accounting is
 :mod:`repro.serve.kvpool`'s. A request
 owns a row of a page *table* (``(B, max_pages) int32``; entry 0 is the
 reserved null page) and a ``lengths`` entry saying how many positions are
-live.
+live. The pools of all layers may share one array: a layer's pages are then
+addressed by its offset added to the table.
 
 One kernel serves both serving phases:
 
@@ -24,8 +28,9 @@ One kernel serves both serving phases:
 Inside the kernel the queries are grouped per kv head: the wrapper lays q
 out as ``(B, KV, C * rep, hd)`` (row ``c * rep + r`` is query position c,
 head ``kv * rep + r``), so each kv head is one ``(C * rep, hd) x (hd,
-page)`` matmul against its K tile and one ``(C * rep, page) x (page, hd)``
-against its V tile — leading-dim indexing only, no strided head split.
+page)`` matmul against its K half and one ``(C * rep, page) x (page, hd)``
+against its V half — leading-dim indexing and one lane split, no strided
+head split.
 
 Grid is ``(B, max_pages)`` with the page dim innermost and *sequential*, so
 Pallas double-buffers the page stream: the next page's DMA (its block index
@@ -65,8 +70,9 @@ PAGED_ATTN_BUFS = 3
 
 def pool_shape(n_pages: int, page_size: int, n_kv_heads: int,
                head_dim: int) -> tuple:
-    """Device array shape of one (unstacked) pool in the fused layout."""
-    return (n_pages, 2 * n_kv_heads, page_size, head_dim)
+    """Device array shape of a pool of ``n_pages`` pages in the lane-dense
+    layout."""
+    return (n_pages, n_kv_heads, page_size, 2 * head_dim)
 
 
 def pool_write(pool: jnp.ndarray, table: jnp.ndarray, rows: jnp.ndarray,
@@ -76,14 +82,19 @@ def pool_write(pool: jnp.ndarray, table: jnp.ndarray, rows: jnp.ndarray,
 
     rows, positions, valid: (N,) — the table row each position belongs to,
     its absolute position, and whether the write is live (dead writes go to
-    the null page 0, which no live row reads). k, v: (N, KV, hd).
+    page 0, which no live table entry points at). k, v: (N, KV, hd).
+
+    One scatter indexed by (page, kv head, slot in page) whose update window
+    is the minor ``2 * hd`` dim alone, so XLA writes the row-major pool in
+    place instead of choosing a layout of its own for the scatter.
     """
     page_size = pool.shape[2]
     pidx = jnp.clip(positions // page_size, 0, table.shape[1] - 1)
     page = jnp.where(valid, table[rows, pidx], 0)
-    n, kv, hd = k.shape
-    kv_rows = jnp.stack([k, v], axis=2).reshape(n, 2 * kv, hd)   # K even, V odd
-    return pool.at[page, :, positions % page_size].set(kv_rows.astype(pool.dtype))
+    heads = jnp.arange(k.shape[1])
+    kv_rows = jnp.concatenate([k, v], axis=-1).astype(pool.dtype)  # (N, KV, 2hd)
+    return pool.at[page[:, None], heads[None, :],
+                   (positions % page_size)[:, None]].set(kv_rows)
 
 
 def _vmem_elems(*shape: int) -> int:
@@ -94,16 +105,17 @@ def _vmem_elems(*shape: int) -> int:
 
 
 def paged_fits(chunk: int, n_heads: int, head_dim: int, page_size: int,
-               kv2: int, *, itemsize: int = COMPUTE_ITEMSIZE) -> bool:
+               n_kv_heads: int, *, itemsize: int = COMPUTE_ITEMSIZE) -> bool:
     """Whether one grid instance's working set fits :data:`VMEM_BUDGET`:
-    ``PAGED_ATTN_BUFS`` page blocks (current + prefetched + compute copy)
-    plus the q and acc blocks ``(KV, C * rep, hd)`` and the two softmax
-    stats ``(KV, C * rep, 1)``, each at its (8, 128)-tile-padded footprint
-    (a stats column takes a full 128-lane tile) and the f32 compute
-    itemsize (same policy as :func:`repro.kernels.tiling.strip_fits`)."""
-    kv = kv2 // 2
+    ``PAGED_ATTN_BUFS`` page blocks ``(KV, page, 2 * hd)`` (current +
+    prefetched + compute copy) plus the q and acc blocks ``(KV, C * rep,
+    hd)`` and the two softmax stats ``(KV, C * rep, 1)``, each at its
+    (8, 128)-tile-padded footprint (a stats column takes a full 128-lane
+    tile) and the f32 compute itemsize (same policy as
+    :func:`repro.kernels.tiling.strip_fits`)."""
+    kv = n_kv_heads
     cr = chunk * (n_heads // kv)
-    page_block = _vmem_elems(kv2, page_size, head_dim)
+    page_block = _vmem_elems(kv, page_size, 2 * head_dim)
     qacc = _vmem_elems(kv, cr, head_dim)
     stats = _vmem_elems(kv, cr, 1)
     working = PAGED_ATTN_BUFS * page_block + 2 * qacc + 2 * stats
@@ -116,7 +128,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, pool_ref, acc_ref, m_ref, l_ref,
     p = pl.program_id(1)
     cr = q_ref.shape[2]                 # C * rep query rows per kv head
     c = cr // rep
-    scale = 1.0 / math.sqrt(q_ref.shape[3])
+    hd = q_ref.shape[3]
+    scale = 1.0 / math.sqrt(hd)
 
     @pl.when(p == 0)
     def _init():
@@ -137,8 +150,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, pool_ref, acc_ref, m_ref, l_ref,
         k_abs = p * page + jax.lax.broadcasted_iota(jnp.int32, (cr, page), 1)
         mask = row >= (k_abs - (length - c)) * rep          # (C*rep, page)
         for j in range(kv):
-            k = pool_ref[0, 2 * j].astype(jnp.float32)       # (page, hd)
-            v = pool_ref[0, 2 * j + 1].astype(jnp.float32)
+            k = pool_ref[0, j, :, :hd].astype(jnp.float32)   # (page, hd)
+            v = pool_ref[0, j, :, hd:].astype(jnp.float32)
             q = q_ref[0, j].astype(jnp.float32) * scale      # (C*rep, hd)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
@@ -162,17 +175,16 @@ def paged_attention_ref(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
     used when :func:`paged_fits` rejects the geometry and as the parity
     oracle in tests."""
     b, c, h, hd = q.shape
-    _, kv2, page, _ = pool.shape
-    kv = kv2 // 2
+    _, kv, page, _ = pool.shape
     rep = h // kv
-    gathered = pool[table].astype(jnp.float32)   # (B, max_pages, 2KV, page, hd)
+    gathered = pool[table].astype(jnp.float32)   # (B, max_pages, KV, page, 2hd)
     s_max = table.shape[1] * page
 
     def heads(x):   # (B, max_pages, KV, page, hd) -> (B, S, KV, hd)
         return jnp.swapaxes(x, 2, 3).reshape(b, s_max, kv, hd)
 
-    k = heads(gathered[:, :, 0::2])
-    v = heads(gathered[:, :, 1::2])
+    k = heads(gathered[..., :hd])
+    v = heads(gathered[..., hd:])
     qg = q.astype(jnp.float32).reshape(b, c, kv, rep, hd) / math.sqrt(hd)
     s = jnp.einsum("bckrd,bpkd->bckrp", qg, k)
     q_abs = lengths[:, None] - c + jnp.arange(c)[None, :]      # (B, C)
@@ -193,7 +205,7 @@ def paged_attention(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
 
     q: (B, C, H, hd) — C == 1 for decode, C == chunk for chunked prefill
     (queries at absolute positions ``lengths - C .. lengths - 1``).
-    pool: (n_pages, 2 * KV, page, hd), K/V on even/odd head indices.
+    pool: (n_pages, KV, page, 2 * hd), K on lanes [0, hd), V on [hd, 2hd).
     table: (B, max_pages) int32 page ids (0 = null page for padding).
     lengths: (B,) int32 live positions per row (0 = inactive row -> zeros).
 
@@ -203,13 +215,12 @@ def paged_attention(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
     retraces through the reference when a kernel launch fails mid-serve.
     """
     b, c, h, hd = q.shape
-    _, kv2, page, hd2 = pool.shape
-    assert hd2 == hd and kv2 % 2 == 0, (pool.shape, q.shape)
-    kv = kv2 // 2
+    _, kv, page, hd2 = pool.shape
+    assert hd2 == 2 * hd, (pool.shape, q.shape)
     assert h % kv == 0, (h, kv)
     rep = h // kv
     max_pages = table.shape[1]
-    if use_ref or not paged_fits(c, h, hd, page, kv2):
+    if use_ref or not paged_fits(c, h, hd, page, kv):
         return paged_attention_ref(q, pool, table, lengths)
 
     cr = c * rep
@@ -224,7 +235,7 @@ def paged_attention(q: jnp.ndarray, pool: jnp.ndarray, table: jnp.ndarray,
             grid=(b, max_pages),
             in_specs=[
                 pl.BlockSpec((1, kv, cr, hd), lambda bi, p, tbl, ln: (bi, 0, 0, 0)),
-                pl.BlockSpec((1, kv2, page, hd),
+                pl.BlockSpec((1, kv, page, 2 * hd),
                              lambda bi, p, tbl, ln: (tbl[bi, p], 0, 0, 0)),
             ],
             out_specs=[

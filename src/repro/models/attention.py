@@ -406,11 +406,12 @@ def attention_paged_decode(p, x: jnp.ndarray, pool: jnp.ndarray,
                            active: jnp.ndarray, cfg: AttnConfig,
                            *, interpret=None,
                            use_ref=False) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One-token paged decode. x: (B, 1, D); pool: (pages, 2KV, P, hd);
-    table: (B, max_pages); lengths: (B,) positions already stored. Writes the
-    new token's K/V at position ``lengths`` (inactive rows are routed to the
-    reserved null page 0, which no table entry of a live row ever points at),
-    then attends over ``lengths + 1`` positions. Returns (y (B, 1, D), pool).
+    """One-token paged decode. x: (B, 1, D); pool: (pages, KV, P, 2 * hd);
+    table: (B, max_pages) page ids of this layer's pages in ``pool``;
+    lengths: (B,) positions already stored. Writes the new token's K/V at
+    position ``lengths`` (inactive rows are routed to page 0, which no table
+    entry of a live row ever points at), then attends over ``lengths + 1``
+    positions. Returns (y (B, 1, D), pool).
     """
     from ..kernels.paged_attention import paged_attention, pool_write
 
@@ -440,10 +441,11 @@ def attention_paged_prefill(p, x: jnp.ndarray, pool: jnp.ndarray,
     """One chunk of paged prefill for a single request. x: (1, C, D) holding
     the prompt tokens at absolute positions ``pos0 .. pos0 + C - 1``;
     positions at chunk index >= ``n_valid`` are padding — their K/V writes are
-    routed to the null page and their outputs are garbage nobody reads (the
+    routed to page 0 and their outputs are garbage nobody reads (the
     caller samples at chunk index ``n_valid - 1``). The in-kernel causal mask
     ``k_abs <= q_abs`` keeps every *valid* query's reduction inside the
-    row's live pages. Returns (y (1, C, D), pool)."""
+    row's live pages. pool and table_row as in
+    :func:`attention_paged_decode`. Returns (y (1, C, D), pool)."""
     from ..kernels.paged_attention import paged_attention, pool_write
 
     c = x.shape[1]

@@ -397,7 +397,8 @@ def decode_step(cfg: ModelConfig, params, cache: DecodeCache, tokens: jnp.ndarra
 class PagedState(NamedTuple):
     """Device state for the paged decode path.
 
-    pools:   {'slot_i': (n_periods, n_pages, 2*KV, page, hd)} per attn slot
+    pools:   {'slot_i': (n_periods * n_pages, KV, page, 2*hd)} per attn slot;
+             period l's pages are ``l * n_pages + table``
     table:   (B, max_pages) int32 page ids; entry 0 = reserved null page
     lengths: (B,) int32 positions already stored per batch row
     active:  (B,) bool — inactive rows write to the null page and attend
@@ -421,17 +422,25 @@ def supports_paged(cfg: ModelConfig) -> bool:
 
 def init_paged_pools(cfg: ModelConfig, n_pages: int, page_size: int,
                      dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
-    """One fused-layout page pool per attention slot, stacked over periods.
-    Page 0 of every pool is the reserved null page (scatter target for
-    inactive/padded writes; never read because those rows report length 0)."""
+    """One lane-dense page pool per attention slot, its periods' pools laid
+    end to end: ``(n_periods * n_pages, KV, page, 2 * hd)``, period ``l``
+    on pages ``[l * n_pages, (l + 1) * n_pages)``. Page 0 of each period is
+    its null page (padded table entries; never read because those rows
+    report length 0); dead writes of every period go to page 0."""
     pools: Dict[str, jnp.ndarray] = {}
     for i, slot in enumerate(cfg.pattern):
         if slot.mixer == "attn":
             pools[f"slot_{i}"] = jnp.zeros(
-                (cfg.n_periods,) + pool_shape(n_pages, page_size,
-                                              cfg.n_kv_heads, cfg.hd),
-                dtype)
+                pool_shape(cfg.n_periods * n_pages, page_size,
+                           cfg.n_kv_heads, cfg.hd), dtype)
     return pools
+
+
+def _period_table(cfg: ModelConfig, pool: jnp.ndarray, table: jnp.ndarray,
+                  idx) -> jnp.ndarray:
+    """``table`` addressing period ``idx``'s pages of a pool laid out by
+    :func:`init_paged_pools`."""
+    return table + idx * (pool.shape[0] // cfg.n_periods)
 
 
 def paged_decode_step(cfg: ModelConfig, params, state: PagedState,
@@ -440,9 +449,10 @@ def paged_decode_step(cfg: ModelConfig, params, state: PagedState,
 
     Returns (logits (B, 1, vocab), ok (B,) bool, new PagedState) — lengths
     advance only on active rows, so a freshly-retired slot can sit idle at
-    no cost. Pools ride in the scan carry exactly like DecodeCache buffers
-    (aliasing across periods keeps live memory at one pool set, not one per
-    period).
+    no cost. Each slot's whole pool rides in the scan carry and every period
+    addresses its own pages through ``table + idx * n_pages``: no period's
+    pool is sliced out or written back, so a caller that donates the pools
+    has them updated in place.
 
     ``ok`` is the **logit health tap**: per-row all-finite flags computed
     on-device, so the serving engine can detect a poisoned slot (NaN/Inf
@@ -469,18 +479,13 @@ def paged_decode_step(cfg: ModelConfig, params, state: PagedState,
             key = f"slot_{i}"
             p = period_params[key]
             if slot.mixer == "attn":
-                with jax.named_scope("kv_pool"):
-                    pool = jax.lax.dynamic_index_in_dim(pools[key], idx, 0,
-                                                        keepdims=False)
                 y, pool = attention_paged_decode(
-                    p["attn"], _norm(cfg, p["mixer_norm"], x), pool,
-                    state.table, state.lengths, state.active, cfg.attn_cfg(),
+                    p["attn"], _norm(cfg, p["mixer_norm"], x), pools[key],
+                    _period_table(cfg, pools[key], state.table, idx),
+                    state.lengths, state.active, cfg.attn_cfg(),
                     use_ref=attn_impl == "ref")
                 x = x + y
-                pools = dict(pools)
-                with jax.named_scope("kv_pool"):
-                    pools[key] = jax.lax.dynamic_update_index_in_dim(
-                        pools[key], pool, idx, 0)
+                pools = {**pools, key: pool}
             if slot.ffn == "dense":
                 x = x + mlp_forward(p["mlp"], _norm(cfg, p["ffn_norm"], x), gated=cfg.gated_mlp)
             elif slot.ffn == "moe":
@@ -531,18 +536,12 @@ def paged_prefill_chunk(cfg: ModelConfig, params, pools: Dict[str, jnp.ndarray],
             key = f"slot_{i}"
             p = period_params[key]
             if slot.mixer == "attn":
-                with jax.named_scope("kv_pool"):
-                    pool = jax.lax.dynamic_index_in_dim(pools[key], idx, 0,
-                                                        keepdims=False)
                 y, pool = attention_paged_prefill(
-                    p["attn"], _norm(cfg, p["mixer_norm"], x), pool,
-                    table_row, pos0, n_valid, cfg.attn_cfg(),
-                    use_ref=attn_impl == "ref")
+                    p["attn"], _norm(cfg, p["mixer_norm"], x), pools[key],
+                    _period_table(cfg, pools[key], table_row, idx), pos0, n_valid,
+                    cfg.attn_cfg(), use_ref=attn_impl == "ref")
                 x = x + y
-                pools = dict(pools)
-                with jax.named_scope("kv_pool"):
-                    pools[key] = jax.lax.dynamic_update_index_in_dim(
-                        pools[key], pool, idx, 0)
+                pools = {**pools, key: pool}
             if slot.ffn == "dense":
                 x = x + mlp_forward(p["mlp"], _norm(cfg, p["ffn_norm"], x), gated=cfg.gated_mlp)
             elif slot.ffn == "moe":

@@ -6,15 +6,16 @@ Serving fast path
 The fast path replaces per-request dense ``(B, S_max)`` KV caches with a
 shared **page pool** per attention slot:
 
-    pool:  (n_pages, 2 * kv_heads, page_size, head_dim)   one per layer slot
+    pool:  (n_periods * n_pages, kv_heads, page_size, 2 * head_dim)
+           one per layer slot, period l on pages l * n_pages + table
     table: (max_slots, max_pages) int32                   page ids per row
     page 0 = reserved null page (padding / inactive-row scatter target)
 
-K and V of each kv head sit on adjacent head indices of one page (K even,
-V odd), so the ragged Pallas decode kernel
-(:mod:`repro.kernels.paged_attention`) streams each page with a single
-double-buffered block DMA, walking the row's page table via scalar
-prefetch. Chunked prefill pushes ``prefill_chunk`` prompt tokens through
+K and V of each kv head sit side by side on the lanes of one page row (K
+on the first ``head_dim``, V on the next), so the ragged Pallas decode
+kernel (:mod:`repro.kernels.paged_attention`) streams each page with a
+single double-buffered block DMA, walking the row's page table via scalar
+prefetch, and the engine's programs update the donated pools in place. Chunked prefill pushes ``prefill_chunk`` prompt tokens through
 the same kernel per step — ``ceil(S/chunk)`` launches instead of ``S``.
 
 The scheduler loop (:mod:`repro.serve.scheduler`) keeps the fixed-shape
@@ -72,7 +73,10 @@ retry. ``ValueError`` remains reserved for requests that could never run.
 
 1. a failing paged-attention launch (decode step or prefill chunk) serves
    exactly that step through the dense ``paged_attention_ref`` path —
-   ``degraded_steps`` counts, one warning total;
+   ``degraded_steps`` counts, one warning total; pools found deleted at a
+   step's start (a call's returned pools were dropped) are made anew and
+   every active request recomputes, as after a preemption — counted there
+   too;
 2. a non-finite logit row (on-device per-slot health tap, no host vocab
    scan) skips sampling for the poisoned slot only and retires it with
    ``finish_reason='nan'`` — the rest of the batch never notices;

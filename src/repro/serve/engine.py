@@ -211,10 +211,16 @@ class Engine:
                                                        row, pos0, nv, tok,
                                                        attn_impl="ref")
 
-            self._decode = jax.jit(paged_decode)
-            self._decode_fallback = jax.jit(paged_decode_ref)
-            self._prefill = jax.jit(paged_prefill)
-            self._prefill_fallback = jax.jit(paged_prefill_ref)
+            # The pools (argument 1, inside the PagedState for decode) are
+            # donated: each call writes them in place and self._pools takes
+            # what it returns. A failed launch raises before anything is
+            # donated, so the fallback still gets live pools.
+            self._decode = jax.jit(paged_decode, donate_argnums=(1,))
+            self._decode_fallback = jax.jit(paged_decode_ref,
+                                            donate_argnums=(1,))
+            self._prefill = jax.jit(paged_prefill, donate_argnums=(1,))
+            self._prefill_fallback = jax.jit(paged_prefill_ref,
+                                             donate_argnums=(1,))
         else:
             def dense_decode(pr, c, t):
                 return transformer.decode_step(model_cfg, pr, c, t)
@@ -313,6 +319,7 @@ class Engine:
                 self._clock_skew += float(skew)
                 self.counters.injected_stalls += 1
             self._expire_deadlines()
+            self._recover_lost_pools()
             if self._admit_freeze > 0:
                 self._admit_freeze -= 1      # backoff: no admissions this step
             else:
@@ -358,9 +365,7 @@ class Engine:
                     ensured.append(slot)
                     break
                 victim = sched.youngest_other(slot, tuple(ensured))
-                vrid = sched.preempt(victim if victim is not None else slot)
-                self._reqs[vrid].preemptions += 1
-                self._reqs[vrid].t_queued = self._now()
+                self._preempt(victim if victim is not None else slot)
                 if victim is None:
                     break              # self-preempted; skip decode this step
 
@@ -544,6 +549,32 @@ class Engine:
                 self.cfg, self.pool.n_pages, self.pool.page_size,
                 self._pool_dtype())
         return self._pools
+
+    def _recover_lost_pools(self) -> None:
+        """The programs consume the pools they are given and return them
+        updated. Pools found deleted mean a caller dropped what a call
+        returned, and with it every active request's K/V: make new pools
+        and requeue each active request to recompute its context on
+        re-admission, as a preemption does. Counted as a degraded step."""
+        if self._pools is None or not any(
+                p.is_deleted() for p in self._pools.values()):
+            return
+        self._pools = None
+        self.counters.degraded_steps += 1
+        self.counters.warn_once(
+            "pools_lost",
+            "the paged KV pools were deleted without a replacement; "
+            "recomputing every active request into new pools (counted in "
+            "ServeMetrics.degraded_steps; warning not repeated)")
+        for slot, _ in list(self.scheduler.active_slots()):
+            self._preempt(slot)
+
+    def _preempt(self, slot: int) -> None:
+        """Requeue ``slot``'s request; it recomputes its context when
+        re-admitted."""
+        rid = self.scheduler.preempt(slot)
+        self._reqs[rid].preemptions += 1
+        self._reqs[rid].t_queued = self._now()
 
     def _decode_call(self, state, tokens):
         """Dispatch one batched decode, degrading to the dense reference

@@ -1,15 +1,16 @@
 """Host-side paged KV pool: fixed pages, per-request tables, freelist.
 
-Device layout (one pool per attention slot, stacked over periods by
-:func:`repro.models.transformer.init_paged_pools`; owned by
+Device layout (one pool per attention slot, its periods' pages laid end
+to end by :func:`repro.models.transformer.init_paged_pools`; owned by
 :mod:`repro.kernels.paged_attention`, see ``pool_shape``):
 
-    (n_pages, 2 * kv_heads, page_size, head_dim)
+    (n_periods * n_pages, kv_heads, page_size, 2 * head_dim)
 
-K and V live *fused* in one page — K on even head indices, V on odd — so
-the decode kernel streams a whole page (both halves) with a single block
-DMA per grid step instead of two, and each head's K or V is one whole
-``(page_size, head_dim)`` tile. Page 0 is the
+K and V live *fused* in one page — K of each kv head on the first
+``head_dim`` lanes of its rows, V on the next — so the decode kernel
+streams a whole page (both halves) with a single block DMA per grid step
+instead of two, and the pool's minor dim fills whole 128-lane vregs. Period
+``l`` reads and writes page ``l * n_pages + id`` for a table id. Page 0 is the
 **reserved null page**: padded table entries and inactive-row scatter
 writes are routed there, and it is never read because those rows report
 length 0 (the kernel's ragged mask skips them), so it can hold arbitrary

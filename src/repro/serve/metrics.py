@@ -36,7 +36,7 @@ class ServeCounters:
                  "injected_poison", "_warned")
 
     def __init__(self) -> None:
-        self.degraded_steps = 0       # kernel launches degraded to the ref path
+        self.degraded_steps = 0       # launches degraded to the ref path, pools lost
         self.nan_retired = 0          # slots retired on a non-finite logit tap
         self.deadline_expired = 0     # requests retired/dropped past deadline
         self.budget_truncated = 0     # wall-clock budget truncations

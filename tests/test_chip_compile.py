@@ -13,7 +13,11 @@ and without a chip:
   * ``paged_attention`` at SmolLM-135M serving widths, decode (C=1, eight
     slots) and one 128-token prefill chunk, over a 2048-position table, and
     at Command R's wider GQA, where the chunk is the largest that the
-    kernel's VMEM fit gate ``paged_fits`` admits.
+    kernel's VMEM fit gate ``paged_fits`` admits;
+  * the serving engine's two programs, ``paged_decode_step`` and
+    ``paged_prefill_chunk``, for SmolLM-135M at the serving benchmark's
+    geometry with the pools donated: no op copies or relayouts a pool or a
+    layer's share of one, and the pool argument aliases the pool output.
 
 Each compile must contain a Mosaic kernel (``tpu_custom_call``): a kernel
 that silently fell back to plain XLA would compile too. Every call passes
@@ -23,7 +27,9 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and test collection
 must not depend on it.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +39,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core import rules_as_tree, table3_rules
 from repro.kernels import megaplan
+from repro.kernels import paged_attention as pa
 from repro.kernels.paged_attention import paged_attention, paged_fits, pool_shape
+from repro.models import transformer
 from repro.kernels.slim_update import PRECOND_BUFS
 
 F32 = jnp.float32
@@ -167,7 +175,98 @@ def test_paged_attention_large_gqa(one_chip, phase):
     prefill case takes the largest admitted chunk up to 128."""
     cfg = get_config("command_r_35b")
     admitted = [c for c in (1, 16, 32, 64, 128)
-                if paged_fits(c, cfg.n_heads, cfg.hd, 16, 2 * cfg.n_kv_heads)]
+                if paged_fits(c, cfg.n_heads, cfg.hd, 16, cfg.n_kv_heads)]
     assert admitted[-1] >= 16, admitted
     slots, chunk = (8, 1) if phase == "decode" else (1, admitted[-1])
     _compile_paged(cfg, slots, chunk, one_chip)
+
+
+# -- the engine's paged programs at the serving cells' geometry --------------
+
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([0-9,]*)\]\S* "
+                     r"([\w\-]+)\((.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+# Ops of a pool's size that move no bytes, or the in-place write itself.
+_NO_COPY = {"parameter", "get-tuple-element", "tuple", "bitcast", "scatter"}
+
+
+def _pool_sized_ops(text: str, sizes) -> list:
+    """(name, opcode) of each op of a compiled module whose result has one
+    of ``sizes`` elements and that may copy it: every op but parameters,
+    tuple plumbing, bitcasts and the scatter, a fusion counting as the op
+    at its root."""
+    roots, ops, comp = {}, [], None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _HLO_OP.match(line)
+        if not m:
+            continue
+        name, dims, opcode, rest = m.groups()
+        if line.lstrip().startswith("ROOT "):
+            roots[comp] = opcode
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        if n in sizes:
+            called = re.search(r"calls=%([\w.\-]+)", rest)
+            ops.append((name, opcode, called.group(1) if called else None))
+    return [(name, op) for name, op, called in ops
+            if (roots.get(called, op) if op == "fusion" else op) not in _NO_COPY]
+
+
+def _aliases(text: str) -> set:
+    """(output index, parameter number) pairs of ``input_output_alias``."""
+    head = text.split("\n", 1)[0]
+    return {(int(o), int(p)) for o, p in
+            re.findall(r"\{(\d+)\}: \((\d+), \{\}", head)}
+
+
+def _entry_types(text: str):
+    """Parameter types and output types of the entry computation."""
+    line = next(ln for ln in text.splitlines() if ln.startswith("ENTRY"))
+    params, out = line.split(") -> (", 1)
+    return (re.findall(r": (\w+\[[0-9,]*\])", params),
+            re.findall(r"\w+\[[0-9,]*\]", out))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_program_writes_the_pool_in_place(one_chip, monkeypatch, program):
+    """SmolLM-135M's decode step and prefill chunk as the serving cells run
+    them (64 slots, 16-token pages, 2048 positions, 8193 pages, 30 layers),
+    with the Mosaic kernel and the pools donated as the engine donates
+    them: no op of a whole pool's size or of one layer's share of it may
+    copy it (the write is one scatter into the donated pool), and the pool
+    argument is the pool output's buffer."""
+    monkeypatch.setattr(pa, "default_interpret",
+                        lambda i=None: False if i is None else i)
+    cfg = get_config("smollm_135m")
+    slots, page, n_pages = 64, 16, 8193
+    max_pages = 2048 // page
+    shape = pool_shape(cfg.n_periods * n_pages, page, cfg.n_kv_heads, cfg.hd)
+
+    def arg(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: arg(a.shape, a.dtype), cfg.abstract()[0])
+    pools = {"slot_0": arg(shape, jnp.bfloat16)}
+    if program == "decode":
+        fn = lambda p, st, t: transformer.paged_decode_step(cfg, p, st, t)
+        state = transformer.PagedState(
+            pools=pools, table=arg((slots, max_pages), jnp.int32),
+            lengths=arg((slots,), jnp.int32), active=arg((slots,), bool))
+        args = (params, state, arg((slots, 1), jnp.int32))
+    else:
+        fn = lambda p, pl_, r, a, n, t: transformer.paged_prefill_chunk(
+            cfg, p, pl_, r, a, n, t)
+        args = (params, pools, arg((1, max_pages), jnp.int32),
+                arg((), jnp.int32), arg((), jnp.int32),
+                arg((1, 128), jnp.int32))
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled program"
+    whole = math.prod(shape)
+    assert _pool_sized_ops(text, {whole, whole // cfg.n_periods}) == []
+    hlo = f"bf16[{','.join(map(str, shape))}]"
+    params_t, outs_t = _entry_types(text)
+    assert params_t.count(hlo) == 1 and outs_t.count(hlo) == 1
+    assert (outs_t.index(hlo), params_t.index(hlo)) in _aliases(text)

@@ -164,6 +164,36 @@ class TestDegradation:
         assert done[rid].finish_reason == "length"
         np.testing.assert_array_equal(done[rid].tokens, clean)
 
+    def test_lost_pools_recompute_with_token_parity(self):
+        """A decode whose returned pools are dropped leaves the engine with
+        the donated, deleted ones: the next step makes new pools and every
+        active request recomputes its context, with the clean tokens."""
+        cfg, params, sc = _mk(max_seq=48, page_size=4, max_new_tokens=6,
+                              prefill_chunk=4)
+        prompts = [_prompt(6, cfg.vocab_size, seed=s) for s in (1, 2)]
+        clean_eng = Engine(cfg, params, sc)
+        rids = [clean_eng.submit(Request(prompt=p)) for p in prompts]
+        clean = clean_eng.run_until_drained()
+
+        eng = Engine(cfg, params, sc)
+        rids = [eng.submit(Request(prompt=p)) for p in prompts]
+        decode = eng._decode
+
+        def drop_pools_once(pr, state, tokens):
+            logits, ok, new = decode(pr, state, tokens)
+            if eng.decode_steps == 1:
+                new = new._replace(pools=state.pools)
+            return logits, ok, new
+
+        eng._decode = drop_pools_once
+        done = eng.run_until_drained()
+        m = eng.metrics()
+        assert m.degraded_steps == 1 and m.preempted == 2
+        for r in rids:
+            assert done[r].finish_reason == "length"
+            np.testing.assert_array_equal(done[r].tokens, clean[r].tokens)
+        assert eng.pool.used_pages == 0
+
     def test_genuine_nan_logits_retire_not_crash(self):
         cfg, params, sc = _mk(max_seq=32, page_size=4, max_new_tokens=4)
         # Corrupt one embedding row; with tied embeddings every logit row

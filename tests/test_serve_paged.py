@@ -14,7 +14,7 @@ import pytest
 
 from repro.configs import get_reduced
 from repro.kernels.paged_attention import (paged_attention, paged_attention_ref,
-                                           paged_fits, pool_shape)
+                                           paged_fits, pool_shape, pool_write)
 from repro.serve import Engine, Request, ServeConfig
 
 
@@ -80,14 +80,42 @@ class TestPagedKernel:
                                        np.asarray(tok[:, 0]),
                                        atol=3e-5, rtol=3e-5)
 
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_pool_write_touches_only_its_layer(self, layer):
+        """K/V written through a table offset to ``layer`` of a stacked pool
+        land in that layer's pages at (page, kv head, slot in page) as K
+        then V on the minor dim; nothing else changes but page 0, where the
+        dead writes go and which no live table entry of any layer reaches."""
+        n_layers, n_pages, page, kv, hd = 3, 7, 4, 2, 8
+        pool = jax.random.normal(jax.random.PRNGKey(3),
+                                 pool_shape(n_layers * n_pages, page, kv, hd))
+        table = np.array([[1, 2, 0], [3, 4, 5]], np.int32)
+        rows = np.array([0, 0, 1, 1, 1], np.int32)
+        positions = np.array([2, 5, 0, 9, 11], np.int32)
+        valid = np.array([True, False, True, True, False])
+        k, v = jax.random.normal(jax.random.PRNGKey(4), (2, 5, kv, hd))
+        out = np.asarray(pool_write(pool, jnp.asarray(table + layer * n_pages),
+                                    jnp.asarray(rows), jnp.asarray(positions),
+                                    jnp.asarray(valid), k, v))
+        want, written = np.array(pool), set()
+        for i in np.flatnonzero(valid):
+            pg = layer * n_pages + table[rows[i], positions[i] // page]
+            want[pg, :, positions[i] % page] = np.concatenate([k[i], v[i]], -1)
+            written.add(pg)
+        np.testing.assert_array_equal(out[1:], want[1:])
+        changed = set(np.flatnonzero((out != np.asarray(pool)).any(axis=(1, 2, 3))))
+        live = {m * n_pages + t for m in range(n_layers) for t in table[table != 0]}
+        assert changed == written | {0} and 0 not in live
+        assert all(layer * n_pages < pg < (layer + 1) * n_pages for pg in written)
+
     def test_fit_gate_charges_padded_stats(self):
         """The softmax stats (KV, C * rep, 1) take a full 128-lane tile each
         on the TPU. With 64 query heads over 8 kv heads at head_dim 128 they
         are as large as the q block, so a 128-token chunk no longer fits
         VMEM_BUDGET while a 32-token one does."""
         h, kv, hd, page = 64, 8, 128, 16
-        assert paged_fits(32, h, hd, page, 2 * kv)
-        assert not paged_fits(128, h, hd, page, 2 * kv)
+        assert paged_fits(32, h, hd, page, kv)
+        assert not paged_fits(128, h, hd, page, kv)
         q = jnp.zeros((1, 128, h, hd), jnp.float32)
         pool = jnp.zeros(pool_shape(3, page, kv, hd), jnp.float32)
         table = jnp.asarray([[1, 2]], jnp.int32)
@@ -130,6 +158,27 @@ class TestEngineParity:
         paged = Engine(cfg, params, ServeConfig(**kw)).generate(prompts)
         legacy = Engine(cfg, params, ServeConfig(paged=False, **kw)).generate(prompts)
         np.testing.assert_array_equal(np.asarray(paged), np.asarray(legacy))
+
+    def test_step_donates_the_pools(self):
+        """Each program writes the pools it is given in place: after one
+        engine step (three prefills and a decode) those arrays are deleted,
+        and the engine goes on to serve the legacy loop's greedy tokens."""
+        cfg, params, _ = _mk("smollm_135m")
+        kw = dict(max_new_tokens=8, max_seq=32, page_size=4)
+        prompts = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (3, 5),
+                                                0, cfg.vocab_size))
+        eng = Engine(cfg, params, ServeConfig(**kw))
+        rids = [eng.submit(Request(prompt=p)) for p in prompts]
+        given = list(eng._device_pools().values())
+        eng.step()
+        assert eng.prefill_chunks == 3 and eng.decode_steps == 1
+        assert all(p.is_deleted() for p in given)
+        assert not any(p.is_deleted() for p in eng._device_pools().values())
+        done = eng.run_until_drained()
+        served = np.stack([np.concatenate([prompts[i], done[r].tokens])
+                           for i, r in enumerate(rids)])
+        legacy = Engine(cfg, params, ServeConfig(paged=False, **kw)).generate(prompts)
+        np.testing.assert_array_equal(served, np.asarray(legacy))
 
     def test_bf16_pool_matches_bf16_legacy_cache(self):
         cfg = dataclasses.replace(get_reduced("gpt_small"),

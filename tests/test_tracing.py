@@ -65,26 +65,47 @@ def test_engine_program_is_named(program):
     assert text.startswith(f"HloModule jit_paged_{program},")
 
 
+_ANY_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([0-9,]*)\]\S* "
+                     r"[\w\-]+\(([^)]*)\)")
+
+
+def _operands(lowered) -> tuple:
+    """Element count of every array op of a lowered program by name, and
+    each op's operand names."""
+    sizes, args = {}, {}
+    for line in lowered.as_text(dialect="hlo").splitlines():
+        m = _ANY_OP.match(line)
+        if m:
+            name, dims, operands = m.groups()
+            sizes[name] = int(np.prod([int(d) for d in dims.split(",") if d]))
+            args[name] = [a.strip().lstrip("%") for a in operands.split(",")]
+    return sizes, args
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_pool_slice_write_and_write_back_are_under_kv_pool(program):
+def test_pool_is_written_in_place_under_kv_pool(program):
+    """Each program writes a layer's K/V with one scatter into the whole
+    stacked pool, under ``kv_pool``; nothing slices a layer's pool out of
+    it, writes one back, or copies either. (On the CPU the Pallas
+    interpreter slices and stores single pages; those are the kernel's.)"""
     eng = _engine()
-    (stacked,) = {p.shape for p in eng._device_pools().values()}
-    layer = stacked[1:]
-    ops = _ops(_lowered(eng, program))
-    pool_ops = {
-        "slice": [o for o in ops if o[3] == "dynamic-slice" and o[2] == (1,) + layer],
-        "write": [o for o in ops if o[3] == "scatter" and o[2] == layer],
-        "write_back": [o for o in ops if o[3] == "dynamic-update-slice"
-                       and o[2] == stacked],
-    }
-    for kind, found in pool_ops.items():
-        assert found, f"no {kind} of a pool in the {program} program"
-        for name, *_, op_name in found:
-            # inside the layer scan, names start at the scan body's root
-            assert "kv_pool" in op_name.split("/"), (kind, name, op_name)
+    (pool,) = eng._device_pools().values()
+    pool_sizes = {pool.size, pool.size // eng.cfg.n_periods}
+    low = _lowered(eng, program)
+    ops = _ops(low)
+    sizes, args = _operands(low)
+    writes = [o for o in ops if o[3] == "scatter"]
+    assert [o[2] for o in writes] == [pool.shape]
+    assert "kv_pool" in writes[0][4].split("/")
+    moved = [o for o in ops
+             if (o[3] in ("dynamic-slice", "copy")
+                 and sizes[o[0]] in pool_sizes)
+             or (o[3] == "dynamic-update-slice"
+                 and sizes[args[o[0]][1]] in pool_sizes)]
+    assert moved == []
     # the attention over the pool is not pool bookkeeping
-    assert not any("kv_pool" in o[4].split("/") and "pallas_call" in o[4]
-                   for o in ops)
+    dots = [o for o in ops if o[3] == "dot"]
+    assert dots and not any("kv_pool" in o[4].split("/") for o in dots)
 
 
 def _trainer(guard=False, **tc):
