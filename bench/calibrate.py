@@ -36,16 +36,16 @@ def train_faults(cell, seed: int, ref: dict) -> dict:
     from bench.reference.common import PRECISIONS, init_params
     from bench.train import CHECK_STEPS, hyper, moment_dims
 
-    cfg, tr = cell.config, cell.traffic
+    cfg, tr, fam = cell.config, cell.traffic, cell.family
     data = traffic.ZipfBatches(cfg["vocab_size"], tr["seq"], tr["batch"], seed,
                                **tr["data"])
     batches = [(b["tokens"], b["labels"])
                for b in (data.batch(s) for s in range(CHECK_STEPS))]
-    make = jax.jit(lambda k: init_params(cfg, k))
+    make = jax.jit(lambda k: init_params(cfg, fam.param_shapes(cfg), k))
     key = jax.random.PRNGKey(traffic.seed32(seed))
 
     def readings(batches, quant, **hp):
-        losses, grad, p3 = adam.train_steps(cfg, make(key), batches,
+        losses, grad, p3 = adam.train_steps(cfg, fam.forward, make(key), batches,
                                             dict(hyper(tr), **hp),
                                             moment_dims(cfg, tr),
                                             rows=tr["check_rows"], quant=quant)

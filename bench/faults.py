@@ -30,12 +30,20 @@ def altered_token(engine):
 
 
 def frozen_pool(engine):
-    """Decode steps return the page pools they were given: no K/V stored."""
+    """Decode steps store no K/V: each runs on a copy of the page pools,
+    which it consumes, and hands back the pools as they were before it (the
+    program donates the pools it is given, so returning those would hand
+    back deleted buffers). Prefill still writes the prompt's K/V, so later
+    decode steps attend over missing entries and their logits go wrong."""
+    import jax
+    import jax.numpy as jnp
+
     decode = engine._decode
 
     def step(params, state, tokens):
-        logits, ok, new = decode(params, state, tokens)
-        return logits, ok, new._replace(pools=state.pools)
+        copy = jax.tree.map(jnp.copy, state.pools)
+        logits, ok, _ = decode(params, state._replace(pools=copy), tokens)
+        return logits, ok, state
 
     engine._decode = step
 

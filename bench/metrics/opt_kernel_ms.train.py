@@ -5,10 +5,7 @@ from bench import counts
 
 
 def read(ctx):
-    calls = ctx.counts["opt_calls"]
-    if ctx.trace is None or not calls:
-        return None
-    n, seconds = ctx.trace.kernel_time(counts.signature(c[2]) for c in calls)
+    n, seconds = counts.kernel_time(ctx, counts.OPT_KERNELS)
     if n == 0:
         return None
     return 1e3 * seconds / ctx.counts["steps"]

@@ -14,8 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import exact, leaf_names
-from .decoder import nll_sum
+from .common import exact, leaf_names, nll_sum
 
 
 def make_update(hp: dict, dims: list):
@@ -53,17 +52,18 @@ def lr_at(hp: dict, t: int) -> float:
     return hp["lr"] * min((t - 1) / hp["warmup_steps"], 1.0)
 
 
-def train_steps(cfg: dict, params, batches, hp: dict, slim_dims: dict, *,
-                rows: int, quant=exact):
-    """Run one step per (tokens, labels) batch from ``params``. A batch's
-    loss and gradient are summed over blocks of ``rows`` rows, so the
-    reference fits the device. Returns (losses, clipped gradient of step 1,
-    params after the last step), the trees in the layout of ``params``."""
+def train_steps(cfg: dict, forward, params, batches, hp: dict, slim_dims: dict,
+                *, rows: int, quant=exact):
+    """Run one step per (tokens, labels) batch from ``params`` through the
+    family's ``forward``. A batch's loss and gradient are summed over blocks
+    of ``rows`` rows, so the reference fits the device. Returns (losses,
+    clipped gradient of step 1, params after the last step), the trees in the
+    layout of ``params``."""
     leaves, treedef = jax.tree_util.tree_flatten(params)
     dims = [tuple(slim_dims.get(n, ())) for n in leaf_names(params)]
     step = make_update(hp, dims)
     vg = jax.jit(jax.value_and_grad(
-        lambda p, tok, lab: nll_sum(cfg, p, tok, lab, quant)))
+        lambda p, tok, lab: nll_sum(forward, cfg, p, tok, lab, quant)))
     m = [jnp.zeros_like(p) for p in leaves]
     v = [jnp.zeros(tuple(1 if i in d else s for i, s in enumerate(p.shape)),
                    jnp.float32) for p, d in zip(leaves, dims)]

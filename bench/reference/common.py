@@ -1,6 +1,7 @@
-"""Parameter layout, initialisation and matmul precision shared by the plain
-references. Parameters use the layer-stacked layout the configuration files
-describe: ``blocks.slot_0.<name>`` leaves carry the layer index first."""
+"""Initialisation, matmul precision and the loss shared by the plain
+references. Each family (``bench/families/``) gives the parameter layout
+(``param_shapes``) and the forward pass; layer-stacked leaves, under
+``blocks.``, carry the layer index first."""
 from __future__ import annotations
 
 import math
@@ -41,31 +42,6 @@ def dot(spec: str, a, b, quant=exact):
                       quant(b.astype(jnp.float32)), precision=HIGHEST)
 
 
-def param_shapes(cfg: dict) -> dict:
-    """Nested dict of leaf shapes in the configuration's stacked layout."""
-    L, D = cfg["num_hidden_layers"], cfg["hidden_size"]
-    H, KV, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    F, V = cfg["intermediate_size"], cfg["vocab_size"]
-    mlp = {"w_up": (L, D, F), "w_down": (L, F, D)}
-    if cfg["hidden_act"] == "silu":
-        mlp["w_gate"] = (L, D, F)
-    shapes = {
-        "embed": (V, D),
-        "blocks": {"slot_0": {
-            "mixer_norm": {"scale": (L, D)},
-            "attn": {"wq": (L, D, H, hd), "wk": (L, D, KV, hd),
-                     "wv": (L, D, KV, hd), "wo": (L, H, hd, D)},
-            "ffn_norm": {"scale": (L, D)},
-            "mlp": mlp,
-        }},
-        "final_norm": {"scale": (D,)},
-    }
-    if cfg["position"] == "learned":
-        shapes["pos_embed"] = (cfg["max_position_embeddings"], D)
-    return shapes
-
-
 def _is_shape(x) -> bool:
     return isinstance(x, tuple)
 
@@ -76,13 +52,13 @@ def leaf_names(tree, is_leaf=None) -> list:
     return [".".join(str(k.key) for k in path) for path, _ in paths]
 
 
-def init_params(cfg: dict, key) -> dict:
-    """Random float32 weights: normal(std), residual writers divided by
-    sqrt(2 L), norm scales 1. Pure and jittable: one call makes them all."""
+def init_params(cfg: dict, shapes: dict, key) -> dict:
+    """Random float32 weights of the layout ``shapes`` (the family's
+    ``param_shapes``): normal(std), residual writers divided by sqrt(2 L),
+    norm scales 1. Pure and jittable: one call makes them all."""
     spec = cfg["init"]
     std = spec["std"]
     resid = std / math.sqrt(2.0 * cfg["num_hidden_layers"])
-    shapes = param_shapes(cfg)
     names = leaf_names(shapes, _is_shape)
     leaves, treedef = jax.tree_util.tree_flatten(shapes, is_leaf=_is_shape)
     out = []
@@ -105,3 +81,12 @@ def norm(cfg: dict, x, scale):
         var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
         y = (x - mu) * jax.lax.rsqrt(var + eps)
     return y * scale
+
+
+def nll_sum(forward, cfg: dict, params, tokens, labels, quant=exact):
+    """Summed next-token negative log-likelihood over every position of the
+    family's ``forward``."""
+    logits = forward(cfg, params, tokens, quant)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    return jnp.sum(lse - gold)

@@ -38,11 +38,13 @@ class Refused(RuntimeError):
 @dataclasses.dataclass
 class Context:
     """What a per-layer reader may read."""
-    trace: object        # trace_reduce.Reduced, or None
-    counts: dict
+    trace: object        # trace_reduce.Reduced (from xplane.reduce), or None
+    counts: dict         # the runner's counts; "program": counters' change
     peak: dict
     config: dict
     traffic: dict
+    family: object       # the configuration's families/<family>.py
+    kernels: dict        # kernel name -> cost function (counts.kernel_roofline)
 
 
 def device_info(chips: int) -> tuple:
@@ -75,9 +77,10 @@ def runners():
 
 def run_cell(cell, seed: int, seconds: float, trace: bool, peak: dict,
              t_start: float, **test_hooks) -> dict:
-    """Drive one run and reduce it to the result line's parts. The tests
-    call this with a small ``model`` and a ``fault``; the CLI does not."""
-    from bench import correct, spec, trace_reduce
+    """Drive one run and reduce it to the result line's parts (and the run's
+    ``counts``). The tests call this with a small ``model`` and a
+    ``fault``; the CLI does not."""
+    from bench import correct, spec, trace_reduce, xplane
 
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     out = runners()[cell.traffic["kind"]](cell, seed, seconds, trace_dir,
@@ -85,19 +88,24 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peak: dict,
     ok, checks = correct.verdict(out["numbers"], cell.limits)
     result = {"correct": ok, "attempted": out["attempted"],
               "failed": out["failed"], "metrics": {}, "checks": checks,
-              "memory_peak_bytes": out["memory_peak_bytes"]}
+              "memory_peak_bytes": out["memory_peak_bytes"],
+              "counts": out["counts"]}
     if not trace:
         values = dict(out["end_to_end"], setup_s=out["setup_s"])
         for m in cell.end_to_end:
             result["metrics"][m["name"]] = {"value": values[m["name"]],
                                             "unit": m["unit"]}
         return result
-    reduced = trace_reduce.reduce_trace(trace_reduce.find_xplane(trace_dir))
+    t0 = time.perf_counter()
+    reduced = xplane.reduce(trace_reduce.find_xplane(trace_dir))
+    print(f"bench: trace reduced in {time.perf_counter() - t0:.1f} s",
+          file=sys.stderr)
     shutil.rmtree(trace_dir, ignore_errors=True)
     ctx = Context(trace=reduced, counts=out["counts"], peak=peak,
-                  config=cell.config, traffic=cell.traffic)
+                  config=cell.config, traffic=cell.traffic, family=cell.family,
+                  kernels=spec.kernels(cell))
     for m in cell.per_layer:
-        value = spec.reader(m["name"])(ctx)
+        value = spec.reader(m["name"], cell.root)(ctx)
         if value is None:
             print(f"bench: per-layer metric {m['name']} found nothing to read "
                   f"in {cell.name}", file=sys.stderr)

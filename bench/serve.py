@@ -26,12 +26,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import counts, program, traffic
+from . import counts, program, spec, traffic
 from .reference.common import PRECISIONS, init_params
-from .reference.decoder import forward
-from .train import peak_bytes, trace_options
+from .train import peak_bytes, stop_trace, trace_options
 
-PAGED_KERNELS = frozenset({"_paged_kernel"})
 BAD_ENDS = ("nan", "deadline", "budget")
 
 
@@ -63,10 +61,12 @@ class EngineSession:
     def __init__(self, cell, seed: int, model=None, fault=None):
         from repro.serve import Engine, Request, ServeConfig
 
-        self.cfg, self.tr, self.seed = cell.config, cell.traffic, seed
-        mc = program.model_config(self.cfg, model)
+        self.cell, self.cfg, self.tr, self.seed = cell, cell.config, cell.traffic, seed
+        self.family = fam = cell.family
+        mc = program.model_config(self.cfg, fam, model)
         key = jax.random.PRNGKey(traffic.seed32(seed))
-        self.make = jax.jit(lambda k: init_params(self.cfg, k))
+        shapes = fam.param_shapes(self.cfg)
+        self.make = jax.jit(lambda k: init_params(self.cfg, shapes, k))
         self.key = key
         sc = ServeConfig(max_new_tokens=self.tr["output"]["max"],
                          **self.tr["serve"])
@@ -79,6 +79,7 @@ class EngineSession:
         self.queued: set = set()
         self.steps: list = []            # per engine step: inside the window?
         self.in_window = False
+        self.moved: dict = {}            # program counters' change in the window
         # Warm-up: one request compiles the prefill chunk, the decode step and
         # the host-side reads; no other shape is used.
         self.eng.submit(Request(prompt=np.arange(2, dtype=np.int32),
@@ -171,8 +172,17 @@ class EngineSession:
                     contexts.append(p + j - 1)
         return calls, contexts
 
-    def paged_signatures(self) -> set:
-        """Result signatures of the paged-attention Pallas calls in the
+    def counters(self) -> dict:
+        return program.counters(self.eng, self.family.counters)
+
+    def pool_signatures(self) -> list:
+        """The type of each of the engine's page pools, as the trace spells
+        a result."""
+        return sorted({counts.array_signature(p)
+                       for p in self.eng._device_pools().values()})
+
+    def kernel_calls(self) -> dict:
+        """Kernel name -> the Pallas calls of the cell's kernels in the
         engine's decode and prefill programs, read from their lowering."""
         from repro.models.transformer import PagedState
 
@@ -189,8 +199,7 @@ class EngineSession:
                                np.int32(1), jnp.zeros((1, sc["prefill_chunk"]),
                                                       jnp.int32)).as_text(),
         ]
-        return {counts.signature(c[2]) for t in texts
-                for c in counts.custom_calls(t, PAGED_KERNELS)}
+        return counts.calls_by_kernel(texts, spec.kernels(self.cell))
 
     # -- the check ----------------------------------------------------------
 
@@ -215,7 +224,7 @@ class EngineSession:
             return {"logit_err": float("inf"), "logit_gap": float("inf")}
         self.served_checked = sum(len(r.completion.tokens) for r in sample)
         cfg, max_seq = self.cfg, self.tr["serve"]["max_seq"]
-        width = self.tr["output"]["max"]
+        width, forward = self.tr["output"]["max"], self.family.forward
         params = self.make(self.key)
 
         @jax.jit
@@ -266,8 +275,14 @@ def _open_window(d: EngineSession, trace_dir):
         jax.profiler.start_trace(trace_dir, profiler_options=trace_options())
     ann = jax.profiler.TraceAnnotation("bench.window")
     ann.__enter__()
-    d.in_window = True
+    d.in_window, d.at_open = True, d.counters()
     return ann
+
+
+def _close_window(d: EngineSession, ann) -> None:
+    ann.__exit__(None, None, None)
+    d.in_window = False
+    d.moved = program.counter_deltas(d.at_open, d.counters())
 
 
 DEPTH_MARKS = (0.25, 0.5, 1.0)
@@ -301,8 +316,8 @@ def run_open(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
             if ann is not None and f not in depth and now >= lo + f * seconds:
                 depth[f] = len(d.eng.scheduler.queue)
         if t_close is None and now >= hi:
-            ann.__exit__(None, None, None)
-            d.in_window, t_close = False, now
+            _close_window(d, ann)
+            t_close = now
             active = len(d.eng.scheduler.active_slots())
         if t_close is not None:
             window = [r for r in d.reqs.values() if lo <= r.due < hi]
@@ -315,7 +330,7 @@ def run_open(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
             with jax.profiler.TraceAnnotation("bench.loadgen.wait"):
                 time.sleep(max(0.0, start + sched[i][0] - time.monotonic()))
     if trace_dir:
-        jax.profiler.stop_trace()
+        stop_trace()
     setup = t_open - clock
     mem = peak_bytes()
     for r in window:
@@ -327,6 +342,9 @@ def run_open(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
     tpot = [r.completion.tpot_s * 1e3 if not r.failed else lost
             for r in window if r.failed or r.completion.tpot_s is not None]
     wait = [(r.admit - r.due) * 1e3 if r.admit is not None else lost for r in window]
+    # queued for a slot, as the program counts it (submit to admission)
+    queued = [getattr(r.completion, "queue_s", None) if not r.failed else lost / 1e3
+              for r in window]
     calls, contexts = d.window_work()
     out = {
         "setup_s": setup,
@@ -338,8 +356,11 @@ def run_open(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
                    "queue_wait_p95_ms": traffic.percentile(wait, 95),
                    "queue_depth": depth, "active_at_close": active,
                    "paged_calls": calls, "contexts": contexts,
-                   "paged_signatures": d.paged_signatures() if trace_dir else set()},
+                   "kernel_calls": d.kernel_calls() if trace_dir else {},
+                   "pool_signatures": d.pool_signatures(), "program": d.moved},
     }
+    if None not in queued:
+        out["counts"]["admit_wait_p95_ms"] = 1e3 * traffic.percentile(queued, 95)
     out["numbers"] = d.check(control)
     out["counts"]["served_checked"] = d.served_checked
     return out
@@ -370,7 +391,7 @@ def run_closed(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
         top_up()
         d.step()
     top_up()
-    sigs = d.paged_signatures() if trace_dir else set()
+    kernel_calls = d.kernel_calls() if trace_dir else {}
     ann = _open_window(d, trace_dir)
     t_open = time.monotonic()
     k0, tok0 = len(d.steps), d.eng.tokens_out
@@ -379,12 +400,11 @@ def run_closed(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
         d.step()
         if time.monotonic() - t_open >= seconds:
             break
-    ann.__exit__(None, None, None)
-    d.in_window = False
+    _close_window(d, ann)
     t_close = time.monotonic()
     k1, tok1 = len(d.steps), d.eng.tokens_out
     if trace_dir:
-        jax.profiler.stop_trace()
+        stop_trace()
     while any(r.completion is None and not r.failed for r in d.watched()) \
             and time.monotonic() - t_close < tr["drain_limit_s"]:
         d.step()
@@ -401,7 +421,8 @@ def run_closed(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
         "memory_peak_bytes": mem,
         "counts": {"window_s": window,
                    "paged_calls": calls, "contexts": contexts,
-                   "paged_signatures": sigs},
+                   "kernel_calls": kernel_calls,
+                   "pool_signatures": d.pool_signatures(), "program": d.moved},
     }
     out["numbers"] = d.check(control)
     out["counts"]["served_checked"] = d.served_checked

@@ -1,5 +1,6 @@
 """Small cells for CPU tests: each configuration at its program's
-``reduced()`` size (float32 compute) and traffic shrunk to fit a test."""
+``reduced()`` size (its family's ``small``, float32 compute) and traffic
+shrunk to fit a test."""
 import json
 
 from repro.configs import get_reduced
@@ -7,16 +8,10 @@ from repro.configs import get_reduced
 from bench import spec
 
 
-def config(name: str):
-    """(configuration dict, the program's ModelConfig) at the reduced size."""
-    cfg = json.loads((spec.BENCH / "configs" / f"{name}.json").read_text())
-    m = get_reduced(name)
-    cfg.update(num_hidden_layers=m.n_layers, hidden_size=m.d_model,
-               num_attention_heads=m.n_heads, num_key_value_heads=m.n_kv_heads,
-               head_dim=m.hd, intermediate_size=m.d_ff, vocab_size=m.vocab_size,
-               compute_dtype="float32")
-    if cfg["position"] == "learned":
-        cfg["max_position_embeddings"] = m.max_position
+def shrink(cfg: dict, family):
+    """(``cfg`` at the reduced size, the program's reduced ModelConfig)."""
+    m = get_reduced(cfg["program"])
+    cfg = dict(family.small(cfg, m), compute_dtype="float32")
     # At this width, weights of std 0.02 leave every position's top logit on
     # its own token whatever the context; at 0.3 the context decides it (as
     # it does at the published width with 0.02), so a broken cache shows.
@@ -24,9 +19,15 @@ def config(name: str):
     return cfg, m
 
 
-def cell(workload: str):
-    c = spec.load_cell(workload)
-    c.config, model = config(c.config["name"])
+def config(name: str):
+    """(configuration dict, the program's ModelConfig) at the reduced size."""
+    cfg = json.loads((spec.BENCH / "configs" / f"{name}.json").read_text())
+    return shrink(cfg, spec.family(cfg["family"]))
+
+
+def cell(workload: str, root=spec.ROOT):
+    c = spec.load_cell(workload, root)
+    c.config, model = shrink(c.config, c.family)
     t = c.traffic
     if t["kind"] == "train":
         t.update(batch=2, seq=32, stride_steps=2, check_rows=1)

@@ -1,6 +1,7 @@
-"""Whole runs of each cell at a small size on the CPU, past the harness's
-look for a chip: sound, ``correct`` is true; with the timed path broken
-underneath in each way the cell can be broken, it is false."""
+"""Whole runs of each cell of ``BENCHMARK.json`` at a small size on the CPU,
+past the harness's look for a chip: sound, ``correct`` is true; with the
+timed path broken underneath in each way the cell can be broken, it is
+false."""
 import json
 import time
 
@@ -13,8 +14,16 @@ PEAK = json.loads((spec.BENCH / "peaks.json").read_text())["devices"]["TPU v5 li
 SEED = 2**33 + 12345
 
 
-TRAIN = ["gpt_small.train_b32", "gpt_small.train_b4"]
-SERVE = ["smollm_135m.serve_chat", "smollm_135m.serve_offline"]
+def _cells(kinds):
+    """Names of the benchmark's cells whose traffic is of one of ``kinds``."""
+    bench = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
+    kind = {w["name"]: json.loads((spec.BENCH / "traffic" / f"{w['traffic']}.json")
+                                  .read_text())["kind"] for w in bench["workloads"]}
+    return [w for w, k in kind.items() if k in kinds]
+
+
+TRAIN = _cells({"train"})
+SERVE = _cells({"serve_open", "serve_closed"})
 
 
 def _run(workload, fault=None):
@@ -40,3 +49,7 @@ def test_broken_run_is_not_correct(workload, fault):
     planted = {**faults.TRAIN, **faults.SERVE}[fault]
     r = _run(workload, planted)
     assert not r["correct"], r["checks"]
+    if fault == "frozen_pool":
+        # a decode that stores no K/V serves wrong logits, and fails nothing
+        assert r["failed"] == 0
+        assert any(c["value"] > c["limit"] for c in r["checks"].values()), r["checks"]

@@ -3,7 +3,7 @@ import json
 
 import pytest
 
-from bench import counts
+from bench import counts, spec
 from bench.spec import BENCH
 
 
@@ -11,23 +11,28 @@ def _cfg(name):
     return json.loads((BENCH / "configs" / f"{name}.json").read_text())
 
 
+def _family(name):
+    return spec.family(_cfg(name)["family"])
+
+
 def test_matmul_params_gpt_small():
     # per layer: q,k,v,o 4 * 768^2 = 2,359,296; MLP 2 * 768 * 3072 =
     # 4,718,592; 12 layers = 84,934,656; tied head 768 * 50304 = 38,633,472
-    assert counts.matmul_params(_cfg("gpt_small")) == 123_568_128
+    assert _family("gpt_small").matmul_params(_cfg("gpt_small")) == 123_568_128
 
 
 def test_matmul_params_smollm():
     # per layer: q,k,v 576 * (9 + 3 + 3) * 64 = 552,960; o 9 * 64 * 576 =
     # 331,776; SwiGLU 3 * 576 * 1536 = 2,654,208; 30 layers = 106,168,320;
     # tied head 576 * 49152 = 28,311,552
-    assert counts.matmul_params(_cfg("smollm_135m")) == 134_479_872
+    assert _family("smollm_135m").matmul_params(_cfg("smollm_135m")) == 134_479_872
 
 
 def test_train_flops_per_token():
     # 6 N = 741,408,768; attention 12 * 12 layers * 12 heads * 64 * 1024 =
     # 113,246,208
-    assert counts.train_flops_per_token(_cfg("gpt_small"), 1024) == 854_654_976
+    cfg = _cfg("gpt_small")
+    assert _family("gpt_small").train_flops_per_token(cfg, 1024) == 854_654_976
 
 
 TINY = {"num_hidden_layers": 1, "hidden_size": 8, "num_attention_heads": 2,
@@ -38,8 +43,9 @@ TINY = {"num_hidden_layers": 1, "hidden_size": 8, "num_attention_heads": 2,
 def test_forward_flops():
     # N = 8 * (2 + 2) * 4 + 2 * 4 * 8 + 3 * 8 * 16 + 8 * 32 = 128 + 64 + 384
     # + 256 = 832; two tokens at contexts 1 and 2: 2 * 832 * 2 + 4 * 2 * 4 * 3
-    assert counts.matmul_params(TINY) == 832
-    assert counts.forward_flops(TINY, [1, 2]) == 2 * 832 * 2 + 4 * 2 * 4 * 3
+    dense = spec.family("dense")
+    assert dense.matmul_params(TINY) == 832
+    assert dense.forward_flops(TINY, [1, 2]) == 2 * 832 * 2 + 4 * 2 * 4 * 3
 
 
 def test_paged_attention_cost():

@@ -5,9 +5,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import correct, traffic
-from bench.reference import adam, common, decoder
+from bench import correct, spec, traffic
+from bench.reference import adam, common
 from bench.tests import small
+
+
+def _init(cfg, key):
+    return common.init_params(cfg, spec.family(cfg["family"]).param_shapes(cfg), key)
 
 
 @pytest.mark.parametrize("name", ["gpt_small", "smollm_135m"])
@@ -15,11 +19,11 @@ def test_forward_matches_program(name):
     from repro.models import transformer
 
     cfg, model = small.config(name)
-    params = common.init_params(cfg, jax.random.PRNGKey(0))
+    params = _init(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0,
                                 cfg["vocab_size"])
     want, _ = transformer.forward(model, params, {"tokens": tokens})
-    got = decoder.forward(cfg, params, tokens)
+    got = spec.family(cfg["family"]).forward(cfg, params, tokens)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
@@ -28,7 +32,8 @@ def test_layout_matches_program(name):
     from bench import program
 
     cfg, model = small.config(name)
-    program.model_config(cfg, model)      # raises where the layout differs
+    # raises where the layout differs
+    program.model_config(cfg, spec.family(cfg["family"]), model)
 
 
 def test_slim_adam_matches_program():
@@ -45,14 +50,14 @@ def test_slim_adam_matches_program():
           "eps": 1e-8, "weight_decay": 0.1, "grad_clip": 1.0}
     data = traffic.ZipfBatches(cfg["vocab_size"], 32, 2, 5, alpha=1.2,
                                n_states=16, mix=0.5, continuations=8)
-    params = common.init_params(cfg, jax.random.PRNGKey(3))
+    params = _init(cfg, jax.random.PRNGKey(3))
     tr = Trainer(model, "slim", learning_rate(hp), data,
                  TrainerConfig(total_steps=3, log_every=1, backend="jnp"))
     tr.params = params
     tr.run(3)
     batches = [(data.batch(s)["tokens"], data.batch(s)["labels"]) for s in range(3)]
-    losses, _, p3 = adam.train_steps(cfg, params, batches, hp,
-                                     cfg["slim_dims"], rows=1)
+    losses, _, p3 = adam.train_steps(cfg, spec.family("gpt2").forward, params,
+                                     batches, hp, cfg["slim_dims"], rows=1)
     np.testing.assert_allclose(losses, [m["loss"] for m in tr.metrics_log],
                                rtol=1e-5)
     gap = correct.norm_gap(correct.diff_norms(tr.params, params),
@@ -62,7 +67,7 @@ def test_slim_adam_matches_program():
 
 def test_slim_dims_compress_the_second_moment():
     cfg, _ = small.config("gpt_small")
-    params = common.init_params(cfg, jax.random.PRNGKey(0))
+    params = _init(cfg, jax.random.PRNGKey(0))
     names = common.leaf_names(params)
     assert set(cfg["slim_dims"]) == set(names)
     wv = params["blocks"]["slot_0"]["attn"]["wv"]

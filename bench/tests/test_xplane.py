@@ -27,7 +27,7 @@ def test_tf_op_paths_of_the_tiny_trace():
 def test_modules_of_the_tiny_trace():
     (mods,) = xplane.device_modules(xplane.read_space(TINY))
     assert [name for *_, name in mods] == ["jit__lambda"] * 3
-    layers = xplane.reduce_layers(TINY)
+    layers = xplane.reduce(TINY)
     calls, seconds = layers.modules["jit__lambda"]
     assert calls == 3 and 0 < seconds < 1e-3
     # each call spans its two ops
@@ -38,7 +38,7 @@ def test_modules_of_the_tiny_trace():
 def test_without_repro_spans_gaps_are_trace_reduces():
     """The window and the gaps, with their lengths and bench.* spans, are
     what trace_reduce reads from ProfileData."""
-    layers = xplane.reduce_layers(TINY)
+    layers = xplane.reduce(TINY)
     reduced = trace_reduce.reduce_trace(TINY)
     assert layers.window_s == reduced.window_s
     assert [s for s, _ in layers.gaps] == [s for s, _ in reduced.gaps]
@@ -49,13 +49,13 @@ def test_without_repro_spans_gaps_are_trace_reduces():
 
 @pytest.fixture(scope="module")
 def scoped():
-    return xplane.reduce_layers(SCOPED, scopes=("inner", "absent"))
+    return xplane.reduce(SCOPED)
 
 
 def test_scope_seconds_of_the_scoped_trace(scoped):
     busy = trace_reduce.reduce_trace(SCOPED).busy_s
     assert 0 < scoped.scope_s["inner"] < busy
-    assert scoped.scope_s["absent"] == 0.0
+    assert "absent" not in scoped.scope_s
     (ops,) = xplane.device_ops(xplane.read_space(SCOPED))
     paths = {path for *_, path in ops}
     assert any("inner" in p.split("/") for p in paths)
@@ -71,3 +71,29 @@ def test_gaps_are_named_by_the_repro_span(scoped):
     # trace_reduce still names them by bench.* spans only
     old = trace_reduce.reduce_trace(SCOPED).gaps[:3]
     assert all(span != "repro.demo.step" for _, span in old)
+
+
+@pytest.mark.parametrize("trace", [TINY, SCOPED])
+def test_reduce_reads_what_trace_reduce_reads(trace):
+    """One pass over the file gives what trace_reduce reads through
+    ProfileData: busy time, each op's self time and the Pallas calls."""
+    mine, base = xplane.reduce(trace), trace_reduce.reduce_trace(trace)
+    assert mine.window_s == base.window_s and mine.n_devices == base.n_devices
+    assert mine.busy_s == pytest.approx(base.busy_s, abs=1e-8)
+    assert mine.self_s.keys() == base.self_s.keys()
+    for k, v in base.self_s.items():
+        assert mine.self_s[k] == pytest.approx(v, abs=1e-8)
+    assert mine.kernels.keys() == base.kernels.keys()
+    for k, (n, s) in base.kernels.items():
+        assert mine.kernels[k][0] == n and mine.kernels[k][1] == pytest.approx(s, abs=1e-8)
+    assert sum(mine.unscoped_s.values()) <= sum(mine.self_s.values()) + 1e-12
+
+
+def test_gaps_are_named_by_the_innermost_span():
+    spans = [(0, 100, "bench.window"), (10, 60, "bench.engine.step"),
+             (20, 40, "repro.serve.readback"), (70, 90, "bench.loadgen.wait")]
+    gaps = [(22, 30), (45, 55), (62, 68), (75, 85), (95, 99)]
+    assert sorted(xplane.name_gaps(gaps, spans)) == sorted([
+        (8e-9, "repro.serve.readback"), (10e-9, "bench.engine.step"),
+        (6e-9, "outside any span"), (10e-9, "bench.loadgen.wait"),
+        (4e-9, "outside any span")])

@@ -31,6 +31,11 @@ class Reduced:
     self_s: dict                  # short op name -> self seconds
     kernels: dict                 # result signature -> [calls, seconds]
     gaps: list                    # (seconds, host span) longest first
+    # filled by ``xplane.reduce``: named scope -> self seconds, program ->
+    # [calls, seconds], result signature -> self seconds of path-less ops
+    scope_s: dict = dataclasses.field(default_factory=dict)
+    modules: dict = dataclasses.field(default_factory=dict)
+    unscoped_s: dict = dataclasses.field(default_factory=dict)
 
     @property
     def idle_share(self) -> float:

@@ -13,25 +13,17 @@ from __future__ import annotations
 
 import gc
 import math
+import sys
 import time
 
 import jax
 import jax.numpy as jnp
 
-from . import correct, counts, program, traffic
+from . import correct, counts, program, spec, traffic
 from .reference import adam as ref_adam
 from .reference.common import init_params
 
 CHECK_STEPS = 3
-# Kernels of the fused optimizer (repro.kernels megaplan / slim_update /
-# fused_adam); the train step runs no other Pallas kernel today.
-OPT_KERNELS = frozenset({
-    "_mega_adam_kernel", "_mega_slim_kernel", "_mega_slim_partial_kernel",
-    "_mega_finalize_ek_kernel", "_mega_finalize_owner_kernel",
-    "_slim_kernel", "_slim_precond_kernel", "_slim_partial_kernel",
-    "_slim_finalize_kernel", "_slim_apply_line_kernel",
-    "_adam_kernel", "_adam_precond_kernel",
-})
 
 
 def _first_moment(opt_state):
@@ -75,10 +67,10 @@ def run(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
     from repro.optim import fused
     from repro.train import Trainer, TrainerConfig
 
-    cfg, tr = cell.config, cell.traffic
-    mc = program.model_config(cfg, model)
+    cfg, tr, fam = cell.config, cell.traffic, cell.family
+    mc = program.model_config(cfg, fam, model)
     key = jax.random.PRNGKey(traffic.seed32(seed))
-    make = jax.jit(lambda k: init_params(cfg, k))
+    make = jax.jit(lambda k: init_params(cfg, fam.param_shapes(cfg), k))
     params = make(key)
     data = traffic.ZipfBatches(cfg["vocab_size"], tr["seq"], tr["batch"], seed,
                                **tr["data"])
@@ -104,16 +96,17 @@ def run(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
                     for n, v in correct.leaf_norms(mu).items()}
     change = correct.diff_norms(trainer.params, params)
     del params
-    opt_calls = []
+    kernel_calls = {}
     if trace_dir:
         batch = {k: jnp.asarray(v) for k, v in data.batch(trainer.step).items()}
         lowered = trainer._train_step.lower(trainer.params, trainer.opt_state,
                                             batch).as_text()
-        opt_calls = counts.custom_calls(lowered, OPT_KERNELS)
+        kernel_calls = counts.calls_by_kernel([lowered], spec.kernels(cell))
 
     stride, failed = tr["stride_steps"], 0
     if trace_dir:
         jax.profiler.start_trace(trace_dir, profiler_options=trace_options())
+    before = program.counters(trainer, fam.counters)
     t_open = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench.window"):
         while True:
@@ -126,7 +119,8 @@ def run(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
         jax.block_until_ready((trainer.params, trainer.opt_state))
     t_close = time.perf_counter()
     if trace_dir:
-        jax.profiler.stop_trace()
+        stop_trace()
+    moved = program.counter_deltas(before, program.counters(trainer, fam.counters))
     steps = trainer.step - CHECK_STEPS
     if fused.kernel_degraded_leaves():
         failed = steps
@@ -139,8 +133,8 @@ def run(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
         "end_to_end": {"train_tokens_per_s": tokens / window},
         "memory_peak_bytes": peak_bytes(),
         "counts": {"steps": steps, "tokens": tokens, "window_s": window,
-                   "flops_per_token": counts.train_flops_per_token(cfg, tr["seq"]),
-                   "opt_calls": opt_calls},
+                   "flops_per_token": fam.train_flops_per_token(cfg, tr["seq"]),
+                   "kernel_calls": kernel_calls, "program": moved},
     }
     prog = {"losses": losses, "grad": grad, "change": change}
     batches = [data.batch(s) for s in range(CHECK_STEPS)]
@@ -150,8 +144,9 @@ def run(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
     # The plain float32 reference, with nothing of the program left on the
     # device: the same weights from the seed, the same three batches.
     ref_losses, ref_grad, ref_params = ref_adam.train_steps(
-        cfg, make(key), [(b["tokens"], b["labels"]) for b in batches], hp,
-        moment_dims(cfg, tr), rows=tr["check_rows"])
+        cfg, fam.forward, make(key),
+        [(b["tokens"], b["labels"]) for b in batches], hp, moment_dims(cfg, tr),
+        rows=tr["check_rows"])
     ref = {"losses": ref_losses, "grad": correct.leaf_norms(ref_grad),
            "change": correct.diff_norms(ref_params, make(key))}
     out["numbers"] = correct.train_numbers(prog, ref)
@@ -162,6 +157,15 @@ def run(cell, seed: int, seconds: float, trace_dir, clock, *, model=None,
 def peak_bytes() -> int:
     stats = jax.devices()[0].memory_stats() or {}
     return int(stats.get("peak_bytes_in_use", 0))
+
+
+def stop_trace() -> None:
+    """Stop the profiler, saying on standard error how long writing the
+    trace took."""
+    t0 = time.perf_counter()
+    jax.profiler.stop_trace()
+    print(f"bench: trace written in {time.perf_counter() - t0:.1f} s",
+          file=sys.stderr)
 
 
 def trace_options():

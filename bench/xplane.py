@@ -10,11 +10,13 @@ op ran in (``jit_paged_decode``). This module reads both straight from the
 the XPlane schema (``tsl/profiler/protobuf/xplane.proto``) it reads; unread
 fields are skipped on parsing.
 
-``reduce_layers`` gives, inside the ``bench.window`` span: device
-self-seconds per named scope, calls and device seconds per program, and
-the idle gaps
-named after the innermost ``bench.*`` or ``repro.*`` host span at their
-midpoint. Times are on the same clock as ``trace_reduce``'s.
+``reduce`` gives, inside the ``bench.window`` span and in one pass over
+the file, what ``trace_reduce.reduce_trace`` reads through ``ProfileData``
+(busy time, each op's self time, Pallas calls by result signature), and
+device self-seconds per named scope, calls and device seconds per program,
+the self-seconds of the ops that have no op path (XLA made them) by result
+signature, and the idle gaps named after the innermost ``bench.*`` or
+``repro.*`` host span at their midpoint: what the per-layer readers get.
 
 An op is under scope ``s`` when ``s`` is one of the scopes of its path
 (``a/s/op`` or ``s/op``). Inside a loop body (a ``lax.scan``) JAX names
@@ -25,13 +27,13 @@ ops inside it.
 from __future__ import annotations
 
 import collections
-import dataclasses
 import functools
+import heapq
 import re
 
 from google.protobuf import descriptor_pb2, descriptor_pool, message_factory
 
-from .trace_reduce import CLOCK_SLACK_NS
+from .trace_reduce import CLOCK_SLACK_NS, Reduced, _union, describe
 
 DEVICE_PLANE = re.compile(r"/device:TPU:\d+")
 SPAN_PREFIXES = ("bench.", "repro.")
@@ -92,16 +94,16 @@ def read_space(path: str):
     return space
 
 
-def _events(plane, line_name: str) -> list:
-    """(start ns, end ns, event metadata) of each event on the named line,
-    in whole nanoseconds as ``ProfileData`` gives them."""
-    meta = {e.key: e.value for e in plane.event_metadata}
+def _events(plane, line_names) -> list:
+    """(start ns, end ns, metadata id) of each event on the lines named in
+    ``line_names``, in whole nanoseconds as ``ProfileData`` gives them."""
     out = []
     for line in plane.lines:
-        if line.name == line_name:
+        if line.name in line_names:
+            t = line.timestamp_ns
             for e in line.events:
-                a = line.timestamp_ns + e.offset_ps // 1000
-                out.append((a, a + e.duration_ps // 1000, meta[e.metadata_id]))
+                a = t + e.offset_ps // 1000
+                out.append((a, a + e.duration_ps // 1000, e.metadata_id))
     return out
 
 
@@ -115,24 +117,35 @@ def _tf_op(md, stat_names: dict) -> str:
     return ""
 
 
+def _device_planes(space) -> list:
+    return [p for p in space.planes if DEVICE_PLANE.fullmatch(p.name)]
+
+
 def device_ops(space) -> list:
     """Per TPU plane, the ``XLA Ops`` events as (start ns, end ns, HLO text,
     op path)."""
     out = []
-    for plane in space.planes:
-        if DEVICE_PLANE.fullmatch(plane.name):
-            names = {s.key: s.value.name for s in plane.stat_metadata}
-            out.append([(a, b, md.name, _tf_op(md, names))
-                        for a, b, md in _events(plane, "XLA Ops")])
+    for plane in _device_planes(space):
+        names = {s.key: s.value.name for s in plane.stat_metadata}
+        meta = {e.key: e.value for e in plane.event_metadata}
+        info = {}
+        events = []
+        for a, b, k in _events(plane, ("XLA Ops",)):
+            if k not in info:
+                info[k] = (meta[k].name, _tf_op(meta[k], names))
+            events.append((a, b) + info[k])
+        out.append(events)
     return out
 
 
 def device_modules(space) -> list:
     """Per TPU plane, the ``XLA Modules`` events as (start ns, end ns,
     program name without its id)."""
-    return [[(a, b, _MODULE_ID.sub("", md.name))
-             for a, b, md in _events(plane, "XLA Modules")]
-            for plane in space.planes if DEVICE_PLANE.fullmatch(plane.name)]
+    out = []
+    for plane in _device_planes(space):
+        name = {e.key: _MODULE_ID.sub("", e.value.name) for e in plane.event_metadata}
+        out.append([(a, b, name[k]) for a, b, k in _events(plane, ("XLA Modules",))])
+    return out
 
 
 def host_spans(space) -> list:
@@ -141,53 +154,82 @@ def host_spans(space) -> list:
     out = []
     for plane in space.planes:
         if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for a, b, md in _events(plane, line.name):
-                    if md.name.startswith(SPAN_PREFIXES):
-                        out.append((a, b, md.name))
+            spans = {e.key: e.value.name for e in plane.event_metadata
+                     if e.value.name.startswith(SPAN_PREFIXES)}
+            lines = {line.name for line in plane.lines}
+            out.extend((a, b, spans[k]) for a, b, k in _events(plane, lines)
+                       if k in spans)
     return out
 
 
-@dataclasses.dataclass
-class Layers:
-    window_s: float
-    scope_s: dict        # named scope -> device self seconds (device mean)
-    modules: dict        # program name -> [calls, device seconds]
-    gaps: list           # (seconds, innermost host span) longest first
+def name_gaps(gaps, spans) -> list:
+    """(seconds, the innermost span other than the window holding the gap's
+    midpoint) of each (start ns, end ns) gap, longest first."""
+    spans = sorted(s for s in spans if s[2] != WINDOW_SPAN)
+    open_, i, named = [], 0, []
+    for a, b in sorted(gaps, key=lambda g: g[0] + g[1]):
+        mid = (a + b) / 2
+        while i < len(spans) and spans[i][0] <= mid:
+            heapq.heappush(open_, (-spans[i][0], spans[i][1], spans[i][2]))
+            i += 1
+        while open_ and open_[0][1] < mid:     # ended before this midpoint
+            heapq.heappop(open_)
+        # the latest-starting span still open holds the midpoint (spans that
+        # ended earlier but started later are popped once they reach the top)
+        named.append(((b - a) / 1e9, open_[0][2] if open_ else "outside any span"))
+    named.sort(key=lambda g: -g[0])
+    return named
 
 
-def reduce_layers(path: str, scopes=()) -> Layers:
-    """The traced window of ``path`` reduced by ``scopes``, programs and
-    host spans."""
+def reduce(path: str, window_span: str = WINDOW_SPAN) -> Reduced:
+    """The traced window of ``path`` reduced in one pass: what
+    ``trace_reduce.reduce_trace`` gives (busy time, each op's self time,
+    Pallas calls by result signature, idle gaps), with the gaps named by
+    the innermost ``bench.*`` or ``repro.*`` span, and device self seconds
+    under each named scope, calls and device seconds per program, and self
+    seconds of path-less ops by result signature."""
     space = read_space(path)
     spans = host_spans(space)
-    windows = [(a, b) for a, b, n in spans if n == WINDOW_SPAN]
+    windows = [(a, b) for a, b, n in spans if n == window_span]
     if len(windows) != 1:
-        raise ValueError(f"{path}: expected one {WINDOW_SPAN!r} span, "
+        raise ValueError(f"{path}: expected one {window_span!r} span, "
                          f"found {len(windows)}")
     ops = device_ops(space)
     if not ops:
-        raise ValueError(f"{path}: no TPU device plane")
+        raise ValueError(f"{path}: no TPU device plane with XLA Ops")
     lo, hi = windows[0][0] - CLOCK_SLACK_NS, windows[0][1] + CLOCK_SLACK_NS
-    scope_s = collections.Counter()
-    gaps = []
+    self_s, scope_s, unscoped_s = (collections.Counter() for _ in range(3))
+    kernels = collections.defaultdict(lambda: [0, 0.0])
+    described, busy, gaps = {}, [], []
     for events in ops:
-        ev = sorted((max(a, lo), min(b, hi), op) for a, b, _, op in events
+        ev = sorted((max(a, lo), min(b, hi), hlo, op) for a, b, hlo, op in events
                     if b > lo and a < hi)
-        stack = []                                   # [end, path, child, dur]
-        for a, b, op in ev + [(hi, hi, None)]:
+        busy.append(_union((a, b) for a, b, *_ in ev))
+        stack = []                          # [end, hlo, path, child, duration]
+        for a, b, hlo, op in ev + [(hi, hi, None, None)]:
             while stack and a >= stack[-1][0]:
-                _, opath, child, dur = stack.pop()
-                for s in set(scopes).intersection(opath.split("/")[:-1]):
-                    scope_s[s] += (dur - child) / 1e9
+                _, text, opath, child, dur = stack.pop()
+                own = (dur - child) / 1e9
+                short, sig, _ = described[text]
+                self_s[short] += own
+                for scope in set(opath.split("/")[:-1]):
+                    scope_s[scope] += own
+                if not opath:
+                    unscoped_s[sig] += own
                 if stack:
-                    stack[-1][2] += dur
-            if op is None:
+                    stack[-1][3] += dur
+            if hlo is None:
                 break
-            stack.append([b, op, 0.0, b - a])
+            if hlo not in described:
+                described[hlo] = describe(hlo)
+            _, sig, pallas = described[hlo]
+            if pallas:
+                kernels[sig][0] += 1
+                kernels[sig][1] += (b - a) / 1e9
+            stack.append([b, hlo, op, 0.0, b - a])
         if events is ops[0]:
             end = lo
-            for a, b, _ in ev:
+            for a, b, *_ in ev:
                 if a > end:
                     gaps.append((end, a))
                 end = max(end, b)
@@ -200,14 +242,10 @@ def reduce_layers(path: str, scopes=()) -> Layers:
                 modules[name][0] += 1
                 modules[name][1] += (min(b, hi) - max(a, lo)) / 1e9
     n = len(ops)
-    named = []
-    for a, b in gaps:
-        mid = (a + b) / 2
-        inside = [s for s in spans if s[0] <= mid <= s[1] and s[2] != WINDOW_SPAN]
-        span = max(inside, key=lambda s: s[0])[2] if inside else "outside any span"
-        named.append(((b - a) / 1e9, span))
-    named.sort(key=lambda g: -g[0])
-    return Layers(window_s=(hi - lo) / 1e9,
-                  scope_s={s: scope_s[s] / n for s in scopes},
-                  modules={k: [c // n, s / n] for k, (c, s) in modules.items()},
-                  gaps=named)
+    return Reduced(window_s=(hi - lo) / 1e9, busy_s=sum(busy) / n / 1e9,
+                   n_devices=n, self_s={k: v / n for k, v in self_s.items()},
+                   kernels={k: [c, s / n] for k, (c, s) in kernels.items()},
+                   gaps=name_gaps(gaps, spans),
+                   scope_s={k: v / n for k, v in scope_s.items()},
+                   modules={k: [c // n, s / n] for k, (c, s) in modules.items()},
+                   unscoped_s={k: v / n for k, v in unscoped_s.items()})
